@@ -118,7 +118,11 @@ def reshard_tree(tree, old_degree: int, new_degree: int, *,
                 f"ZeRO reshard {old_degree}->{new_degree} corrupted "
                 f"leaf {jax.tree_util.keystr(path)} "
                 f"(shape {a.shape}, dtype {a.dtype})")
-        out.append(jnp.asarray(full))
+        # jnp would narrow 64-bit leaves while x64 is off: such a leaf
+        # stays a numpy array so the reshard keeps its dtype
+        out.append(jnp.asarray(full)
+                   if jax.dtypes.canonicalize_dtype(full.dtype) == full.dtype
+                   else full)
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -30,6 +30,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
+import jax
 import numpy as np
 
 from ..checkpoint import CheckpointManager
@@ -142,21 +143,29 @@ class Supervisor:
             on_restore: Optional[Callable] = None,
             log_every: int = 10) -> Any:
         """Run ``n_steps`` with checkpoint/restart.  ``step_fn(state,
-        batch) -> (state, metrics)``.  Returns the final state."""
+        batch) -> (state, metrics)``; it may donate ``state``.  Each
+        step's ``dt`` ends when its outputs are ready on the device.
+        Returns the final state."""
         step = int(state["step"]) if "step" in state else 0
         # pristine restart snapshot: a failure BEFORE the first
-        # checkpoint must rewind the data stream too (jnp leaves are
-        # immutable, so keeping references is a faithful snapshot)
-        init_state, init_step = state, step
+        # checkpoint must rewind the data stream too.  It is a host copy:
+        # holding the device arrays would keep a second full state on
+        # the device for the whole run, and a donating step_fn deletes
+        # them anyway
+        leaves, treedef = jax.tree_util.tree_flatten(state)
+        init_host = jax.device_get(leaves)
+        init_sharding = [getattr(x, "sharding", None) for x in leaves]
+        init_step = step
         init_loader_state = dict(self.loader.state_dict())
         while step < n_steps:
             try:
                 if self.injector:
                     self.injector.check(step)
                 batch = self.loader.next_batch()
-                t0 = time.time()
+                t0 = time.perf_counter()
                 state, metrics = step_fn(state, batch)
-                dt = time.time() - t0
+                jax.block_until_ready((state, metrics))
+                dt = time.perf_counter() - t0
                 self.watchdog.observe(step, dt)
                 step += 1
                 rec = {"step": step, "dt": dt,
@@ -179,7 +188,9 @@ class Supervisor:
                 if latest is None:
                     # no checkpoint yet: true from-scratch restart —
                     # model state AND stream position back to pristine
-                    state = init_state
+                    state = treedef.unflatten(
+                        [jax.device_put(h, sh) for h, sh
+                         in zip(init_host, init_sharding)])
                     self.loader.load_state_dict(dict(init_loader_state))
                     step = init_step
                     continue

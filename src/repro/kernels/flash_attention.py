@@ -76,16 +76,18 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     n_rep = hq // hkv
+    # up to block_q query rows are one whole block; more are tiled by
+    # block_q (a multiple of 8) with zero-padded rows, sliced off below
     bq = min(block_q, sq)
-    while sq % bq:
-        bq //= 2
-    bq = max(bq, 1)
+    sq_pad = -(-sq // bq) * bq
+    if sq_pad != sq:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_pad - sq), (0, 0)))
     nk = -(-skv // block_kv)
     pad = nk * block_kv - skv
     if pad:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    qf = q.reshape(b * hq, sq, d)
+    qf = q.reshape(b * hq, sq_pad, d)
     kf = k.reshape(b * hkv, nk * block_kv, d)
     vf = v.reshape(b * hkv, nk * block_kv, d)
 
@@ -95,7 +97,7 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
         q_offset=q_offset)
     out = pl.pallas_call(
         kernel,
-        grid=(b * hq, sq // bq, nk),
+        grid=(b * hq, sq_pad // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
             pl.BlockSpec((1, block_kv, d),
@@ -104,7 +106,7 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
                          lambda h, i, j, n_rep=n_rep: (h // n_rep, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * hq, sq_pad, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),       # running max m
             pltpu.VMEM((bq,), jnp.float32),       # running denom l
@@ -112,4 +114,4 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool = True,
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(b, hq, sq, d)
+    return out.reshape(b, hq, sq_pad, d)[:, :, :sq]

@@ -25,25 +25,29 @@ def _gmm_kernel(x_ref, w_ref, o_ref):
 def moe_gmm_pallas(x: jax.Array, w: jax.Array,
                    block_m: int = BLOCK_M, block_n: int = BLOCK_N,
                    interpret: bool = True) -> jax.Array:
-    """x: (E, cap, d), w: (E, d, f) -> (E, cap, f)."""
+    """x: (E, cap, d), w: (E, d, f) -> (E, cap, f).  A dim no longer
+    than its block is one whole block; a longer one is tiled by the
+    block (``block_m`` a multiple of 8, ``block_n`` of 128) and
+    zero-padded to a multiple of it."""
     e, cap, d = x.shape
     f = w.shape[-1]
     bm = min(block_m, cap)
-    while cap % bm:
-        bm //= 2
-    bm = max(bm, 1)
     bn = min(block_n, f)
-    while f % bn:
-        bn //= 2
-    bn = max(bn, 1)
-    return pl.pallas_call(
+    cap_pad = -(-cap // bm) * bm
+    f_pad = -(-f // bn) * bn
+    if cap_pad != cap:
+        x = jnp.pad(x, ((0, 0), (0, cap_pad - cap), (0, 0)))
+    if f_pad != f:
+        w = jnp.pad(w, ((0, 0), (0, 0), (0, f_pad - f)))
+    out = pl.pallas_call(
         _gmm_kernel,
-        grid=(e, cap // bm, f // bn),
+        grid=(e, cap_pad // bm, f_pad // bn),
         in_specs=[
             pl.BlockSpec((1, bm, d), lambda ei, i, j: (ei, i, 0)),
             pl.BlockSpec((1, d, bn), lambda ei, i, j: (ei, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda ei, i, j: (ei, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, cap, f), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((e, cap_pad, f_pad), x.dtype),
         interpret=interpret,
     )(x, w)
+    return out[:, :cap, :f]

@@ -1,9 +1,10 @@
 """Jitted wrappers for the Pallas kernels + impl-registry hookup.
 
-On TPU the kernels compile natively; everywhere else they run with
-``interpret=True`` (the kernel body executes step-by-step on CPU), which
-is how correctness is validated in this container.  ``register_kernels``
-swaps them into the model layers' impl registry.
+On TPU the kernels compile natively; on the CPU they run with
+``interpret=True`` (the kernel body executes step-by-step), which is how
+their correctness is tested.  Any other platform is refused rather than
+silently interpreted.  ``register_kernels`` swaps them into the model
+layers' impl registry.
 """
 from __future__ import annotations
 
@@ -20,7 +21,12 @@ from .rmsnorm import rmsnorm_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels target TPU; platform {platform} has no "
+            "compiled path (only the CPU runs them interpreted)")
+    return platform == "cpu"
 
 
 # ---- flash attention: Pallas forward + jnp flash backward (custom VJP)

@@ -26,24 +26,26 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 def rmsnorm_pallas(x: jax.Array, w: jax.Array, eps: float = 1e-6,
                    block_rows: int = BLOCK_ROWS,
                    interpret: bool = True) -> jax.Array:
-    """x: (..., D), w: (D,)."""
+    """x: (..., D), w: (D,).  Up to ``block_rows`` rows form one whole
+    block; more are tiled ``block_rows`` (a multiple of 8) at a time,
+    zero-padding the last block (a zero row normalizes to zero)."""
     orig_shape = x.shape
     d = x.shape[-1]
     n = x.size // d
     x2 = x.reshape(n, d)
     br = min(block_rows, n)
-    while n % br:
-        br //= 2
-    br = max(br, 1)
+    n_pad = -(-n // br) * br
+    if n_pad != n:
+        x2 = jnp.pad(x2, ((0, n_pad - n), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
-        grid=(n // br,),
+        grid=(n_pad // br,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_pad, d), x.dtype),
         interpret=interpret,
     )(x2, w)
-    return out.reshape(orig_shape)
+    return out[:n].reshape(orig_shape)

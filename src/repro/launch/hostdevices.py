@@ -53,7 +53,13 @@ def ensure_host_devices(n: int, *, verify: bool = True) -> int:
     if not verify:
         return n
     import jax
-    have = len(jax.devices())
+    devs = jax.devices()
+    have = len(devs)
+    if have < n and devs[0].platform != "cpu":
+        raise RuntimeError(
+            f"the plan needs {n} devices but platform "
+            f"{devs[0].platform} has {have} ({devs[0].device_kind}); "
+            "host devices are faked only on the cpu platform")
     if have < n:
         raise RuntimeError(
             f"jax initialized with {have} device(s) before "

@@ -146,11 +146,8 @@ def lower_cell(cfg: ArchConfig, mesh: Mesh, strat: ShardingRules,
     amap = axis_map_for(strat)
     amap["mesh"] = mesh
     L.set_axis_map(amap)
-    # jax < 0.6 has no jax.set_mesh; entering the Mesh context manager
-    # provides the same ambient mesh for the lowering
-    set_mesh = getattr(jax, "set_mesh", None)
     try:
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        with jax.set_mesh(mesh):
             if kind == "train":
                 fn, avals = jit_train_step(cfg, mesh, strat, shape_name)
             elif kind == "prefill":

@@ -1,7 +1,8 @@
-"""End-to-end training driver (deliverable b): trains a reduced (or
-~100M-parameter) model for a few hundred steps on whatever devices are
-available, with the full substrate — data pipeline, AdamW + schedule,
-checkpoint/restart via the FT supervisor.
+"""End-to-end training driver (deliverable b): trains a shipped config
+at its published widths, or a reduced one when any of ``--d-model``,
+``--layers`` or ``--vocab`` is given, on the default device, with the
+full substrate — data pipeline, AdamW + schedule, checkpoint/restart via
+the FT supervisor.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
       --steps 200 --d-model 256 --layers 4
@@ -12,7 +13,10 @@ the run resumes from the last checkpoint with the exact data stream.
 from __future__ import annotations
 
 import argparse
+import functools
+import math
 import pathlib
+import statistics
 import time
 
 import jax
@@ -28,7 +32,9 @@ from repro.optim import adamw_init, adamw_update, cosine_schedule, \
 
 
 def build_step(cfg, lr_fn):
-    @jax.jit
+    # the state is donated: params and both AdamW moments are updated in
+    # place, so a full-width step holds one copy of them, not two
+    @functools.partial(jax.jit, donate_argnums=(0,))
     def step(state, batch):
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
         loss, grads = jax.value_and_grad(
@@ -40,6 +46,45 @@ def build_step(cfg, lr_fn):
                  "step": state["step"] + 1},
                 {"loss": loss, "gnorm": gnorm, "lr": lr})
     return step
+
+
+# widths of the reduced config when only some of them are given
+_REDUCED = {"d_model": 256, "layers": 4, "vocab": 2048}
+
+
+def model_config(base, d_model=None, layers=None, vocab=None):
+    """``base`` as published when no width is given; otherwise its
+    reduced same-family config (float32, no remat) at the given widths,
+    the others taking ``_REDUCED``'s."""
+    if d_model is None and layers is None and vocab is None:
+        return base
+    d = d_model or _REDUCED["d_model"]
+    return base.reduced(n_layers=layers or _REDUCED["layers"],
+                        d_model=d, d_ff=d * 4,
+                        vocab=vocab or _REDUCED["vocab"],
+                        n_heads=max(4, d // 64))
+
+
+def device_line() -> str:
+    devs = jax.devices()
+    return (f"platform={devs[0].platform} kind={devs[0].device_kind} "
+            f"count={len(devs)}")
+
+
+def run_backend(cfg, strat, backend: str, tokens: int):
+    """One real training step of ``strat`` on the proxy program of
+    ``cfg`` over ``tokens`` tokens, on the named backend.  Returns
+    (executor, RunResult, batch)."""
+    from repro import tune
+    from repro.runtime.executor import make_executor
+    prog, _ = tune.build_strategy_program(cfg, strat, tokens)
+    # the proxy compiles against ShapeDtypeStructs; real execution
+    # materializes them from fixed seeds, so every backend sees the same
+    # params and batch
+    batch = tune.synth_batch(prog)
+    params = tune.materialize_params(prog.params)
+    ex = make_executor(backend, prog, params=params)
+    return ex, ex.run(batch), batch
 
 
 class _ProgramLoader:
@@ -179,9 +224,11 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--d-model", type=int, default=256)
-    ap.add_argument("--layers", type=int, default=4)
-    ap.add_argument("--vocab", type=int, default=2048)
+    # widths default to the config's own; giving any of them trains the
+    # reduced config instead (the others default to _REDUCED)
+    ap.add_argument("--d-model", type=int, default=None)
+    ap.add_argument("--layers", type=int, default=None)
+    ap.add_argument("--vocab", type=int, default=None)
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
@@ -198,8 +245,9 @@ def main(argv=None):
     ap.add_argument("--backend", default=None,
                     choices=list(list_backends()),
                     help="execute one real training step of the "
-                    "replayed --strategy on the reduced config's proxy "
-                    "program on the named runtime backend — "
+                    "replayed --strategy on the config's proxy program "
+                    "(same widths as training, --batch x --seq tokens) "
+                    "on the named runtime backend — "
                     + backends_help())
     # elastic fault tolerance (repro.ft.elastic): run a short training
     # loop on the replayed --strategy, kill a rank mid-run, and let the
@@ -246,7 +294,10 @@ def main(argv=None):
                     "repro.tune.DEFAULT_TOKENS)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     base = get_config(args.arch)
+    cfg = model_config(base, args.d_model, args.layers, args.vocab)
     budget_bytes = None
     if args.memory_budget is not None:
         budget_bytes = int(args.memory_budget * 2**30)
@@ -283,10 +334,12 @@ def main(argv=None):
         from repro.runtime.executor import get_backend_spec
         backend_caps = (get_backend_spec(args.backend).capabilities
                         if args.backend else None)
-        if backend_caps is not None and backend_caps.real_xla:
-            # a real-XLA backend must fake the mesh's host device count
-            # BEFORE anything touches jax devices (capability flag, not
-            # a backend-name compare)
+        if backend_caps is not None and backend_caps.real_xla \
+                and jax.config.jax_platforms == "cpu":
+            # on the CPU, a real-XLA backend must fake the mesh's host
+            # device count BEFORE anything touches jax devices
+            # (capability flag, not a backend-name compare); any other
+            # platform runs on its real devices
             if strat.mesh is None:
                 print(f"strategy: --backend {args.backend} needs a "
                       "structured strategy with a Mesh (mesh-less "
@@ -334,38 +387,22 @@ def main(argv=None):
 
         if args.backend:
             # one REAL training step of the same strategy document, on
-            # the reduced config's proxy program (the full-size proxy
-            # would be untractable on host devices)
-            exec_cfg = base.reduced(
-                n_layers=args.layers, d_model=args.d_model,
-                d_ff=args.d_model * 4, vocab=args.vocab,
-                n_heads=max(4, args.d_model // 64))
-            pipe = strat.pipeline
-            # per-microbatch tokens must shard over each stage's
-            # replicate group — its width is every non-pipeline axis,
-            # whatever the data axis is named
-            group = (strat.mesh.n_devices
-                     // strat.mesh.axis_size(pipe.axis)
-                     if strat.mesh else 1)
-            tokens_exec = pipe.n_mb * max(group, 1) * 8
-            prog2, _ = tune.build_strategy_program(exec_cfg, strat,
-                                                   tokens_exec)
-            # the proxy compiles against ShapeDtypeStructs; real
-            # execution materializes them (small: the REDUCED config)
-            batch = tune.synth_batch(prog2)
-            params_real = tune.materialize_params(prog2.params)
+            # the proxy program of the config being trained
+            tokens_exec = args.batch * args.seq
             if args.elastic:
-                return run_elastic(prog2, params_real,
-                                   exec_cfg.vocab, args,
+                prog2, _ = tune.build_strategy_program(cfg, strat,
+                                                       tokens_exec)
+                return run_elastic(prog2,
+                                   tune.materialize_params(prog2.params),
+                                   cfg.vocab, args,
                                    schedule=chaos_schedule)
-            from repro.runtime.executor import make_executor
-            ex = make_executor(args.backend, prog2, params=params_real)
-            res = ex.run(batch)
+            ex, res, batch = run_backend(cfg, strat, args.backend,
+                                         tokens_exec)
             if backend_caps.measured_time:
                 ms = ex.measure(batch, reps=3) * 1e3
                 print(f"backend[{args.backend}] loss={res.loss:.6f}  "
                       f"measured_step={ms:.2f}ms on "
-                      f"{res.stats['devices']} host devices "
+                      f"{res.stats['devices']} devices, {device_line()} "
                       f"({res.stats['tasks']} plan tasks)")
             else:
                 print(f"backend[{args.backend}] loss={res.loss:.6f}  "
@@ -395,13 +432,13 @@ def main(argv=None):
         print(f"plan saved to {plan_path} "
               f"({len(plan.directives())} directives); winning strategy "
               f"saved to {strat_path} (replay with --strategy)")
-    cfg = base.reduced(n_layers=args.layers, d_model=args.d_model,
-                       d_ff=args.d_model * 4, vocab=args.vocab,
-                       n_heads=max(4, args.d_model // 64))
     n_params = cfg.param_count()
-    print(f"arch={cfg.name} ({cfg.family}) reduced to "
-          f"{n_params/1e6:.1f}M params, {args.steps} steps "
-          f"batch={args.batch} seq={args.seq}")
+    print(f"arch={cfg.name} ({cfg.family}) "
+          f"{'as published' if cfg is base else 'reduced'}: "
+          f"{n_params/1e6:.1f}M params, {cfg.n_layers} layers, "
+          f"d_model={cfg.d_model}, vocab={cfg.vocab}, {cfg.dtype}, "
+          f"remat={cfg.remat}; {args.steps} steps "
+          f"batch={args.batch} seq={args.seq}; {device_line()}")
 
     params = init(cfg, jax.random.PRNGKey(0))
     state = {"params": params, "opt": adamw_init(params),
@@ -431,7 +468,20 @@ def main(argv=None):
           f"({args.batch*args.seq*len(sup.history)/wall:.0f} tok/s) — "
           f"loss {losses[0]:.3f} -> {losses[-1]:.3f}, "
           f"restarts={sup.restarts}, stragglers={len(sup.watchdog.events)}")
-    assert losses[-1] < losses[0], "loss did not decrease"
+    # the first step compiles; the median of the rest is the step time
+    steady = [h["dt"] for h in sup.history[1:]]
+    if steady:
+        print(f"step_time_median={statistics.median(steady)*1e3:.3f}ms "
+              f"over {len(steady)} steps after a warm-up step")
+    stats = jax.devices()[0].memory_stats()
+    if stats and "peak_bytes_in_use" in stats:
+        print(f"peak_bytes_in_use={stats['peak_bytes_in_use']}")
+    if not all(math.isfinite(x) for x in losses):
+        print("train: a loss was not finite")
+        return 1
+    if not losses[-1] < losses[0]:
+        print("train: the loss did not decrease")
+        return 1
     return 0
 
 

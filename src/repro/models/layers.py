@@ -47,17 +47,22 @@ def set_axis_map(mapping: Optional[dict]) -> None:
 
 
 def constrain(x, *logical):
-    """with_sharding_constraint on logical axes ('dp'/'tp'/None).
-    Falls back to unconstrained when the spec doesn't apply (no ambient
-    mesh, or a dim not divisible by the axis size)."""
+    """with_sharding_constraint on logical axes ('dp'/'tp'/None) under
+    the mesh of ``set_axis_map``.  A dim that the axis size does not
+    divide stays unconstrained; any other failure raises."""
     if not _AXIS_MAP:
         return x
     from jax.sharding import PartitionSpec as P
-    axes = [(_AXIS_MAP.get(a) if a else None) for a in logical]
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*axes))
-    except Exception:
-        return x
+    mesh = _AXIS_MAP.get("mesh")
+    axes = []
+    for dim, a in zip(x.shape, logical):
+        ax = _AXIS_MAP.get(a) if a else None
+        if ax is not None and mesh is not None:
+            names = (ax,) if isinstance(ax, str) else tuple(ax)
+            if dim % int(np.prod([mesh.shape[n] for n in names])):
+                ax = None
+        axes.append(ax)
+    return jax.lax.with_sharding_constraint(x, P(*axes))
 
 
 # ---------------------------------------------------------------------------
@@ -404,7 +409,6 @@ def moe_block_ep(p, x, *, n_experts: int, top_k: int, act: str = "swiglu",
     replicated across tp and the combine is a2a, not an all-reduce —
     per-device traffic drops from O(T*d) to O(T*K*d/tp).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -497,7 +501,7 @@ def moe_block_ep(p, x, *, n_experts: int, top_k: int, act: str = "swiglu",
         return y_tok.reshape(bl, sl, d), aux
 
     dp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, tp_axis, None),      # x: batch@dp, seq@tp
                   P(None, None, None),       # router (wrapped, see call)
@@ -505,7 +509,7 @@ def moe_block_ep(p, x, *, n_experts: int, top_k: int, act: str = "swiglu",
                   P(tp_axis, None, None),    # we_up
                   P(tp_axis, None, None)),   # we_down
         out_specs=(P(dp, tp_axis, None), P()),
-        check_rep=False)
+        check_vma=False)
     router = p["router"][None]               # add a dummy leading axis
     wg = p.get("we_gate", p["we_up"])
     y, aux = f(x, router, wg, p["we_up"], p["we_down"])

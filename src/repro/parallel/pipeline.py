@@ -21,7 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
@@ -73,13 +72,13 @@ def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
         contrib = jnp.where(r == R - 1, y_acc, jnp.zeros_like(y_acc))
         return jax.lax.psum(contrib, axis)
 
-    f = shard_map(
+    f = jax.shard_map(
         per_rank, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(
             lambda a: P(*([axis] + [None] * (a.ndim - 1))),
             params_stacked), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return f(params_stacked, x_microbatches)
 

@@ -24,10 +24,11 @@ mirror of §12's SPMD table):
   p2p recv              ordered ``io_callback`` blocking on that channel
                         and dynamically type-checking the payload
                         against the receiver's wired ``ValueSpec``
-  all_gather (param)    the rank's 1/|group| byte shard of the bucket's
-                        bit-cast params goes through a subgroup
-                        rendezvous; the callback returns the full byte
-                        vector, rebuilt in-trace into the gathered tree
+  all_gather (param)    the rank's 1/|group| shard of the bucket's
+                        params, bit-cast to unsigned words per width,
+                        goes through a subgroup rendezvous; the callback
+                        returns the full word vector, rebuilt in-trace
+                        into the gathered tree
                         the consuming chunks read (load-bearing, exactly
                         like the SPMD lowering)
   all_reduce /          every member posts its locally accumulated
@@ -128,7 +129,7 @@ from ..core.plan import ROLE_COLL, ROLE_COMPUTE, ROLE_RECV, ROLE_SEND
 from ..core.scheduler import validate_comm_order
 from .executor import jaxpr_eqn_count, register_backend
 from .interpreter import RunResult, ScheduleReplay, _PlanWalker
-from .spmd import _bytes_to_tree, _tree_to_bytes, gather_chunk_args
+from .spmd import _concat_words, _split_words, gather_chunk_args
 
 tree_map = jax.tree_util.tree_map
 
@@ -381,16 +382,16 @@ def _ensure_sync_cpu_dispatch() -> None:
     exists it must be rebuilt.  Old arrays stay readable (np.asarray
     re-transfers), but device handles captured before the rebuild go
     stale — hence this runs before ``__init__`` touches
-    ``jax.devices()``.
+    ``jax.devices()``.  On any other platform it does nothing: clearing
+    the backends there would drop the accelerator's client and every
+    array already placed on it.
     """
-    if not bool(getattr(jax.config, "jax_cpu_enable_async_dispatch",
-                        True)):
+    if not jax.config.read("jax_cpu_enable_async_dispatch") \
+            or jax.default_backend() != "cpu":
         return
     jax.config.update("jax_cpu_enable_async_dispatch", False)
-    from jax._src import xla_bridge as _xb
-    if getattr(_xb, "_backends", None):
-        import jax.extend.backend as _jeb
-        _jeb.clear_backends()
+    import jax.extend.backend as _jeb
+    _jeb.clear_backends()
 
 
 # ---------------------------------------------------------------------------
@@ -665,9 +666,19 @@ class MpmdExecutor:
             chosen = [avail[p] for p in phys]
         else:
             # unlike SPMD (one shard_map over n mesh devices), rank
-            # programs are independent executables — oversubscribing
-            # fewer real devices is allowed (rank r -> device r mod D),
-            # which is what lets world-4 smoke tests run on 1 CPU device
+            # programs are independent executables — on the CPU,
+            # oversubscribing fewer devices is allowed (rank r -> device
+            # r mod D), which is what lets world-4 smoke tests run on 1
+            # CPU device.  An accelerator runs one program at a time, so
+            # two ranks whose callbacks wait on each other would hang on
+            # one chip
+            platform = avail[0].platform
+            if platform != "cpu" and len(avail) < self.n:
+                raise MpmdBackendError(
+                    f"plan spans {self.n} ranks but platform {platform} "
+                    f"has {len(avail)} device(s) "
+                    f"({avail[0].device_kind}); the MPMD backend needs "
+                    "one device per rank off the CPU")
             chosen = [avail[i % len(avail)] for i in range(self.n)]
         self.physical_devices = tuple(
             d.id if hasattr(d, "id") else i for i, d in enumerate(chosen))
@@ -1021,35 +1032,30 @@ class MpmdExecutor:
         if g <= 1:
             gathered[node.id] = {b: prm[b] for b in buckets}
             return
-        # fused buckets cross the wire as ONE concatenated byte payload
-        flats, metas = [], []
-        for bkt in buckets:
-            u8, recipe = _tree_to_bytes(prm[bkt])
-            flats.append(u8)
-            metas.append((bkt, recipe, int(u8.size)))
-        cat = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-        total = int(cat.size)
-        chunk = -(-total // g)  # ceil: pad to g equal shards
-        padded = (jnp.concatenate(
-            [cat, jnp.zeros((chunk * g - total,), cat.dtype)])
-            if chunk * g != total else cat)
+        # fused buckets cross the wire as ONE concatenated payload per
+        # word width
+        cats, metas = _concat_words([prm[bkt] for bkt in buckets])
         pos = group.index(r)
-        shard = padded[pos * chunk:(pos + 1) * chunk]
         nid = node.id
+        fulls = {}
+        for dt, cat in cats.items():
+            total = int(cat.size)
+            chunk = -(-total // g)  # ceil: pad to g equal shards
+            padded = (jnp.concatenate(
+                [cat, jnp.zeros((chunk * g - total,), cat.dtype)])
+                if chunk * g != total else cat)
+            shard = padded[pos * chunk:(pos + 1) * chunk]
 
-        def cb(sh):
-            parts = self.transport.gather(
-                ("gather", self._gen, nid), pos, g, np.asarray(sh),
-                self.timeout)
-            return np.concatenate(parts)[:total]
+            def cb(sh, dt=dt, total=total):
+                parts = self.transport.gather(
+                    ("gather", self._gen, nid, dt), pos, g, np.asarray(sh),
+                    self.timeout)
+                return np.concatenate(parts)[:total]
 
-        full = io_callback(cb, jax.ShapeDtypeStruct((total,), np.uint8),
-                           shard, ordered=True)
-        out, off = {}, 0
-        for bkt, recipe, nb in metas:
-            out[bkt] = _bytes_to_tree(full[off:off + nb], recipe)
-            off += nb
-        gathered[node.id] = out
+            fulls[dt] = io_callback(
+                cb, jax.ShapeDtypeStruct((total,), cat.dtype), shard,
+                ordered=True)
+        gathered[node.id] = dict(zip(buckets, _split_words(fulls, metas)))
 
     def _trace_grad_reduce(self, r, node, grad_acc, grad_cnt, built,
                            toks):

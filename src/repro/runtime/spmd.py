@@ -2,9 +2,10 @@
 
 The reference ``Interpreter`` *simulates* devices (one Python loop, one
 jit per chunk, no wire traffic).  This module lowers the same plan into
-ONE ``jax.jit`` + ``shard_map`` program over N real XLA devices — on CI,
-host-platform devices faked with ``--xla_force_host_platform_device_count``
-(``launch.hostdevices.ensure_host_devices``); on TPU/GPU, the physical
+ONE ``jax.jit`` + ``shard_map`` program over N real XLA devices — on the
+CPU, host-platform devices faked with
+``--xla_force_host_platform_device_count``
+(``launch.hostdevices.ensure_host_devices``); on TPU, the physical
 chips — so every collective in the plan becomes a real XLA collective on
 the wire, in the plan's dispatch order.
 
@@ -16,14 +17,15 @@ IR-op -> lax lowering (DESIGN.md §12 has the full table):
                         only its own plan slice)
   p2p send/recv         ``lax.ppermute`` with the node's (src, dst)
                         pairs (non-destinations receive zeros)
-  all_gather (param)    the bucket's params, bit-cast to one byte
-                        vector, sharded 1/|group| per rank and
-                        reassembled with ``lax.all_gather(tiled=True)``
-                        over the subgroup; consuming chunks read the
-                        GATHERED tree (the collective is load-bearing —
-                        XLA cannot dead-code it away).  A fused node
+  all_gather (param)    the bucket's params, bit-cast to one unsigned
+                        word vector per width, sharded 1/|group| per
+                        rank and reassembled with
+                        ``lax.all_gather(tiled=True)`` over the
+                        subgroup; consuming chunks read the GATHERED
+                        tree (the collective is load-bearing — XLA
+                        cannot dead-code it away).  A fused node
                         (overlap engine) concatenates its member
-                        buckets' bytes into ONE collective.
+                        buckets' words into ONE collective per width.
   all_reduce (grad)     ``lax.psum`` of the locally accumulated,
                         1/count-prescaled bucket grads over the replica
                         subgroup (fused members concatenate per dtype
@@ -85,11 +87,6 @@ from jax import lax
 from jax.sharding import Mesh as XlaMesh
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..core.compiler import CompiledProgram
 from ..core.dag import Node, TrainingDAG
 from ..core.plan import ROLE_SEND
@@ -106,44 +103,8 @@ tree_leaves = jax.tree_util.tree_leaves
 
 
 # ---------------------------------------------------------------------------
-# byte/flat codecs (bit-exact tree <-> vector, for wire collectives)
+# flat codecs (bit-exact tree <-> per-dtype vectors, for wire collectives)
 # ---------------------------------------------------------------------------
-
-def _tree_to_bytes(tree):
-    """Flatten a pytree to one uint8 vector (bit-exact, dtype-agnostic).
-    Returns (u8, recipe); ``_bytes_to_tree`` inverts."""
-    leaves, treedef = tree_flatten(tree)
-    chunks, recipe = [], []
-    for l in leaves:
-        dt = jnp.dtype(l.dtype)
-        if dt == jnp.uint8:
-            u8 = l.reshape(-1)
-        else:
-            u8 = lax.bitcast_convert_type(l, jnp.uint8).reshape(-1)
-        chunks.append(u8)
-        recipe.append((tuple(l.shape), dt))
-    u8 = (jnp.concatenate(chunks) if len(chunks) > 1
-          else chunks[0] if chunks else jnp.zeros((0,), jnp.uint8))
-    return u8, (treedef, recipe)
-
-
-def _bytes_to_tree(u8, recipe):
-    treedef, leaf_recipe = recipe
-    leaves, off = [], 0
-    for shape, dt in leaf_recipe:
-        n = int(np.prod(shape, dtype=np.int64)) if shape else 1
-        nbytes = n * dt.itemsize
-        seg = u8[off:off + nbytes]
-        off += nbytes
-        if dt == jnp.uint8:
-            leaves.append(seg.reshape(shape))
-        elif dt.itemsize == 1:
-            leaves.append(lax.bitcast_convert_type(seg.reshape(shape), dt))
-        else:
-            leaves.append(lax.bitcast_convert_type(
-                seg.reshape(tuple(shape) + (dt.itemsize,)), dt))
-    return tree_unflatten(treedef, leaves)
-
 
 def _flatten_by_dtype(tree):
     """Flatten a (gradient) pytree into one 1-D vector per dtype.
@@ -167,6 +128,66 @@ def _unflatten_by_dtype(flats, recipe):
     leaves = [flats[dt][off:off + n].reshape(shape)
               for (dt, off, n, shape) in leaf_recipe]
     return tree_unflatten(treedef, leaves)
+
+
+_WORDS = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
+
+
+def _tree_to_words(tree):
+    """Bit-exact flattening for gathers: each leaf is bitcast to the
+    unsigned int of its own width and flattened per width.  (Bitcasting
+    to bytes instead adds a trailing dim of itemsize, which the TPU tiles
+    to 128 lanes: a 64-fold blow-up of a bf16 bucket.)  Returns
+    ({word dtype: 1-D}, recipe); ``_words_to_tree`` inverts."""
+    dtypes = tree_map(lambda l: jnp.dtype(l.dtype), tree)
+    words = tree_map(lambda l: lax.bitcast_convert_type(
+        l, _WORDS[jnp.dtype(l.dtype).itemsize]), tree)
+    flats, recipe = _flatten_by_dtype(words)
+    return flats, (recipe, dtypes)
+
+
+def _words_to_tree(flats, recipe):
+    recipe, dtypes = recipe
+    return tree_map(lax.bitcast_convert_type,
+                    _unflatten_by_dtype(flats, recipe), dtypes)
+
+
+def _concat_flats(flats_list):
+    """Concatenate several trees' per-dtype flats (a fused collective's
+    members) into one vector per dtype.  Returns ({dtype: 1-D},
+    [{dtype: (offset, size)}] per member)."""
+    per_dt: dict[str, list] = {}
+    bounds = []
+    for flats in flats_list:
+        b = {}
+        for dt, flat in flats.items():
+            lst = per_dt.setdefault(dt, [])
+            b[dt] = (sum(int(x.size) for x in lst), int(flat.size))
+            lst.append(flat)
+        bounds.append(b)
+    cats = {dt: (jnp.concatenate(lst) if len(lst) > 1 else lst[0])
+            for dt, lst in per_dt.items()}
+    return cats, bounds
+
+
+def _split_flats(fulls, bounds):
+    """Inverse of ``_concat_flats``: each member's per-dtype flats."""
+    return [{dt: fulls[dt][off:off + n] for dt, (off, n) in b.items()}
+            for b in bounds]
+
+
+def _concat_words(trees):
+    """``_concat_flats`` over the word flattening of each tree.  Returns
+    ({word dtype: 1-D}, metas); ``_split_words`` inverts."""
+    words = [_tree_to_words(t) for t in trees]
+    cats, bounds = _concat_flats([f for f, _ in words])
+    return cats, list(zip([r for _, r in words], bounds))
+
+
+def _split_words(fulls, metas):
+    recipes = [r for r, _ in metas]
+    return [_words_to_tree(f, r) for f, r in
+            zip(_split_flats(fulls, [b for _, b in metas]), recipes)]
 
 
 def gather_chunk_args(dag: TrainingDAG, node: Node, feeds, store):
@@ -255,11 +276,17 @@ class SpmdExecutor:
         self._idx = {d: i for i, d in enumerate(self.devices)}
         avail = jax.devices()
         if len(avail) < self.n:
+            platform = avail[0].platform
+            if platform != "cpu":
+                raise SpmdBackendError(
+                    f"plan spans {self.n} devices but platform "
+                    f"{platform} has {len(avail)} "
+                    f"({avail[0].device_kind})")
             raise SpmdBackendError(
                 f"plan spans {self.n} devices but jax sees only "
-                f"{len(avail)}; fake host devices with launch.hostdevices."
-                "ensure_host_devices(n) BEFORE jax initializes (tests use "
-                "a subprocess with XLA_FLAGS="
+                f"{len(avail)} cpu device(s); fake host devices with "
+                "launch.hostdevices.ensure_host_devices(n) BEFORE jax "
+                "initializes (tests use a subprocess with XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={self.n})")
         if physical_devices is not None:
             # elastic recovery: map the n logical plan ranks onto the
@@ -359,8 +386,9 @@ class SpmdExecutor:
             seen.add(nid)
             trace_order.append(nid)
         traced = self._make_traced(trace_order, b)
-        sm = _shard_map(traced, mesh=self.mesh, in_specs=(P(), P(AXIS)),
-                        out_specs=P(AXIS), check_rep=False)
+        sm = jax.shard_map(traced, mesh=self.mesh,
+                           in_specs=(P(), P(AXIS)), out_specs=P(AXIS),
+                           check_vma=False)
         b.traced_sm = sm
         b.fn = jax.jit(sm)
         return b
@@ -504,27 +532,21 @@ class SpmdExecutor:
         if g <= 1:
             gathered[node.id] = {b: prm[b] for b in buckets}
             return
-        # fused buckets lower as ONE concatenated byte collective
-        flats, metas = [], []
-        for b in buckets:
-            u8, recipe = _tree_to_bytes(prm[b])
-            flats.append(u8)
-            metas.append((b, recipe, int(u8.size)))
-        cat = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-        total = int(cat.size)
-        chunk = -(-total // g)  # ceil: pad to g equal shards
-        padded = (jnp.concatenate(
-            [cat, jnp.zeros((chunk * g - total,), cat.dtype)])
-            if chunk * g != total else cat)
+        # fused buckets lower as ONE concatenated collective per word
+        # width (one in all for a bf16 model)
+        cats, metas = _concat_words([prm[b] for b in buckets])
         pos = rank % g  # local position within the aligned subgroup
-        shard = lax.dynamic_slice(padded, (pos * chunk,), (chunk,))
-        full = lax.all_gather(shard, AXIS, axis_index_groups=subs,
-                              tiled=True)[:total]
-        out, off = {}, 0
-        for b, recipe, nbytes in metas:
-            out[b] = _bytes_to_tree(full[off:off + nbytes], recipe)
-            off += nbytes
-        gathered[node.id] = out
+        fulls = {}
+        for dt, cat in cats.items():
+            total = int(cat.size)
+            chunk = -(-total // g)  # ceil: pad to g equal shards
+            padded = (jnp.concatenate(
+                [cat, jnp.zeros((chunk * g - total,), cat.dtype)])
+                if chunk * g != total else cat)
+            shard = lax.dynamic_slice(padded, (pos * chunk,), (chunk,))
+            fulls[dt] = lax.all_gather(shard, AXIS, axis_index_groups=subs,
+                                       tiled=True)[:total]
+        gathered[node.id] = dict(zip(buckets, _split_words(fulls, metas)))
 
     def _trace_grad_reduce(self, node, grad_acc, grad_cnt, acc_devs,
                            reduced, built):
@@ -551,19 +573,9 @@ class SpmdExecutor:
             scaled.append(flats)
             recipes.append(recipe)
             contrib.append(max(len(acc_devs.get(bkt, set()) & group), 1))
-        per_dtype: dict[str, list] = {}
-        bounds: list[dict[str, tuple[int, int]]] = []
-        for flats in scaled:
-            d = {}
-            for dt, flat in flats.items():
-                lst = per_dtype.setdefault(dt, [])
-                off = sum(int(x.size) for x in lst)
-                lst.append(flat)
-                d[dt] = (off, int(flat.size))
-            bounds.append(d)
+        cats, bounds = _concat_flats(scaled)
         summed: dict[str, Any] = {}
-        for dt, lst in per_dtype.items():
-            cat = jnp.concatenate(lst) if len(lst) > 1 else lst[0]
+        for dt, cat in cats.items():
             if g <= 1:
                 summed[dt] = cat
             elif node.op == "all_reduce":
@@ -580,10 +592,8 @@ class SpmdExecutor:
                 summed[dt] = lax.all_gather(
                     shard, AXIS, axis_index_groups=subs,
                     tiled=True)[:total]
-        for (bkt, accumulated), recipe, d, n_contrib in zip(
-                members, recipes, bounds, contrib):
-            flats = {dt: summed[dt][off:off + n]
-                     for dt, (off, n) in d.items()}
+        for (bkt, accumulated), recipe, flats, n_contrib in zip(
+                members, recipes, _split_flats(summed, bounds), contrib):
             mean = tree_map(lambda x: x / n_contrib,
                             _unflatten_by_dtype(flats, recipe))
             if bkt in reduced and not accumulated:

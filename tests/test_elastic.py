@@ -38,6 +38,28 @@ _ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 # ---------------------------------------------------------------------------
+# ZeRO shard remap of whole trees
+# ---------------------------------------------------------------------------
+
+class TestReshardTree:
+    @pytest.mark.parametrize("dtype", ["float32", "float64", "bfloat16",
+                                       "int32"])
+    @pytest.mark.parametrize("old,new", [(1, 1), (2, 3), (4, 1)])
+    def test_keeps_dtype_and_bits(self, dtype, old, new):
+        """A reshard is a placement change: each leaf comes back with
+        its own dtype and bytes, 64-bit leaves included while x64 is
+        off."""
+        from repro.checkpoint import reshard_tree
+        rng = np.random.default_rng(0)
+        leaf = rng.standard_normal((5, 3)).astype(
+            jax.numpy.dtype(dtype))
+        out = reshard_tree({"w": leaf, "b": leaf.ravel()[:1]}, old, new)
+        for got, want in ((out["w"], leaf), (out["b"], leaf.ravel()[:1])):
+            assert np.asarray(got).dtype == want.dtype
+            assert np.asarray(got).tobytes() == want.tobytes()
+
+
+# ---------------------------------------------------------------------------
 # mesh-shrink planner
 # ---------------------------------------------------------------------------
 

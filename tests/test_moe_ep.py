@@ -24,8 +24,7 @@ SCRIPT = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (B, S, D)) * 0.5
 
     kw = dict(n_experts=E, top_k=K, act="swiglu", capacity_factor=8.0)
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         def f_ep(p, x):
             y, aux = L.moe_block_ep(p, x, mesh=mesh, dp_axes=("data",),
                                     tp_axis="model", **kw)
