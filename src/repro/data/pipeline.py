@@ -14,6 +14,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -163,7 +164,10 @@ class TokenLoader:
         self.state = state or DataState(seed=getattr(source, "seed", 0))
 
     def next_batch(self) -> dict:
-        blk = self.source.block(self.state.step, self.batch, self.seq)
+        """The next (tokens, labels) batch; the source's work is the
+        profiler span ``data.block``."""
+        with TraceAnnotation("data.block"):
+            blk = self.source.block(self.state.step, self.batch, self.seq)
         per = self.batch // self.n_hosts
         mine = blk[self.host_id * per:(self.host_id + 1) * per]
         self.state.step += 1

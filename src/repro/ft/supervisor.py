@@ -32,6 +32,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..checkpoint import CheckpointManager
 # exception root + unified injectors live in ft.chaos (PR 7); re-exported
@@ -145,7 +146,11 @@ class Supervisor:
         """Run ``n_steps`` with checkpoint/restart.  ``step_fn(state,
         batch) -> (state, metrics)``; it may donate ``state``.  Each
         step's ``dt`` ends when its outputs are ready on the device.
-        Returns the final state."""
+        Each step, from its batch to its history record, is a profiler
+        step ``train_step`` (``step_num`` the step it completes) holding
+        the spans ``ft.sync`` (the wait for the device) and ``ft.metrics``
+        (the metrics' host copies, the watchdog, the record).  Returns
+        the final state."""
         step = int(state["step"]) if "step" in state else 0
         # pristine restart snapshot: a failure BEFORE the first
         # checkpoint must rewind the data stream too.  It is a host copy:
@@ -161,16 +166,19 @@ class Supervisor:
             try:
                 if self.injector:
                     self.injector.check(step)
-                batch = self.loader.next_batch()
-                t0 = time.perf_counter()
-                state, metrics = step_fn(state, batch)
-                jax.block_until_ready((state, metrics))
-                dt = time.perf_counter() - t0
-                self.watchdog.observe(step, dt)
-                step += 1
-                rec = {"step": step, "dt": dt,
-                       **{k: float(v) for k, v in metrics.items()}}
-                self.history.append(rec)
+                with StepTraceAnnotation("train_step", step_num=step + 1):
+                    batch = self.loader.next_batch()
+                    t0 = time.perf_counter()
+                    state, metrics = step_fn(state, batch)
+                    with TraceAnnotation("ft.sync"):
+                        jax.block_until_ready((state, metrics))
+                    dt = time.perf_counter() - t0
+                    with TraceAnnotation("ft.metrics"):
+                        self.watchdog.observe(step, dt)
+                        step += 1
+                        rec = {"step": step, "dt": dt,
+                               **{k: float(v) for k, v in metrics.items()}}
+                        self.history.append(rec)
                 if log_every and step % log_every == 0:
                     print(f"  step {step}: loss={rec.get('loss'):.4f} "
                           f"({dt*1e3:.0f} ms)", flush=True)

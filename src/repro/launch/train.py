@@ -464,15 +464,15 @@ def main(argv=None):
     state = sup.run(state, step_fn, args.steps)
     wall = time.time() - t0
     losses = [h["loss"] for h in sup.history]
-    print(f"done: {len(sup.history)} steps in {wall:.1f}s "
-          f"({args.batch*args.seq*len(sup.history)/wall:.0f} tok/s) — "
+    print(f"done: {len(sup.history)} steps in {wall:.1f}s — "
           f"loss {losses[0]:.3f} -> {losses[-1]:.3f}, "
           f"restarts={sup.restarts}, stragglers={len(sup.watchdog.events)}")
     # the first step compiles; the median of the rest is the step time
     steady = [h["dt"] for h in sup.history[1:]]
     if steady:
         print(f"step_time_median={statistics.median(steady)*1e3:.3f}ms "
-              f"over {len(steady)} steps after a warm-up step")
+              f"over {len(steady)} steps after a warm-up step, dispatch "
+              f"to device ready (the loader excluded)")
     stats = jax.devices()[0].memory_stats()
     if stats and "peak_bytes_in_use" in stats:
         print(f"peak_bytes_in_use={stats['peak_bytes_in_use']}")

@@ -6,6 +6,12 @@ Layers are stacked (leading ``n_layers`` axis) and applied under
 whole stack shards under pjit.  Training remat is per-layer
 (``jax.checkpoint`` around the scan body, policy configurable).
 
+A decoder layer's attention and dense MLP, each with its pre-norm, run
+under ``jax.named_scope("attn")`` and ``("mlp")``, and the LM head with
+the cross-entropy under ``("lm_head_ce")``: the names reach the
+compiled ops' metadata, where a device trace finds them (forward, remat
+recompute and backward alike).
+
 Public entry points (all pure):
   init(cfg, key)                         -> params
   train_loss(cfg, params, batch)         -> scalar loss
@@ -295,9 +301,10 @@ def _dec_layer(cfg, lp, x, enc_out=None, cross_lp=None,
                                 unroll_chunks=cfg.unroll_scans,
                                 chunk=cfg.ssm_chunk)
         return x + h, jnp.zeros((), jnp.float32)
-    a, _ = L.attention_block(lp["attn"], _norm(cfg, lp["norm1"], x), cfg,
-                             mrope_positions=mrope_positions,
-                             causal=cfg.causal)
+    with jax.named_scope("attn"):
+        a, _ = L.attention_block(lp["attn"], _norm(cfg, lp["norm1"], x),
+                                 cfg, mrope_positions=mrope_positions,
+                                 causal=cfg.causal)
     x = x + a
     if cross_lp is not None:
         # cross attention: keys/values from the encoder output
@@ -305,13 +312,12 @@ def _dec_layer(cfg, lp, x, enc_out=None, cross_lp=None,
                         _norm(cfg, cross_lp["norm"], x), enc_out)
         x = x + c
     aux = jnp.zeros((), jnp.float32)
-    h = _norm(cfg, lp["norm2"], x)
     if cfg.moe:
-        m, aux = _moe_dispatch(cfg, lp["moe"], h)
-        x = x + m
+        m, aux = _moe_dispatch(cfg, lp["moe"], _norm(cfg, lp["norm2"], x))
     else:
-        x = x + L.mlp_block(lp["mlp"], h, cfg.act)
-    return x, aux
+        with jax.named_scope("mlp"):
+            m = L.mlp_block(lp["mlp"], _norm(cfg, lp["norm2"], x), cfg.act)
+    return x + m, aux
 
 
 def _moe_dispatch(cfg, moe_params, h):
@@ -444,8 +450,8 @@ def train_loss(cfg: ArchConfig, p: dict, batch: dict) -> jax.Array:
         enc_out = _run_encoder(cfg, p, batch["frames"].astype(cfg.jdtype))
     h, aux = _run_decoder(cfg, p, x, enc_out=enc_out,
                           mrope_positions=batch.get("mrope_positions"))
-    labels = batch["labels"]
-    loss = _ce_loss(cfg, p, h, labels)
+    with jax.named_scope("lm_head_ce"):
+        loss = _ce_loss(cfg, p, h, batch["labels"])
     return loss + 0.01 * aux
 
 

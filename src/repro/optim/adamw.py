@@ -1,6 +1,7 @@
 """AdamW with fp32 moments (m, v), decoupled weight decay and global-norm
 clipping.  Pure functions over pytrees so pjit shards the moments with
-the ZeRO rules in parallel/sharding.py."""
+the ZeRO rules in parallel/sharding.py.  ``adamw_update`` runs under
+``jax.named_scope("adamw")``, so a device trace finds its ops."""
 from __future__ import annotations
 
 
@@ -20,6 +21,7 @@ def global_norm(tree) -> jax.Array:
                         for l in jax.tree_util.tree_leaves(tree)))
 
 
+@jax.named_scope("adamw")
 def adamw_update(params, grads, opt, lr, *, b1=0.9, b2=0.95, eps=1e-8,
                  weight_decay=0.1, clip_norm=1.0):
     gnorm = global_norm(grads)
