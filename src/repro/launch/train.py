@@ -13,10 +13,13 @@ the run resumes from the last checkpoint with the exact data stream.
 from __future__ import annotations
 
 import argparse
+import collections
 import functools
+import json
 import math
 import pathlib
 import statistics
+import sys
 import time
 
 import jax
@@ -27,6 +30,7 @@ from repro.configs import get_config
 from repro.data import SyntheticTokenSource, TokenLoader
 from repro.ft import FailureInjector, Supervisor
 from repro.models import init, train_loss
+from repro.models.layers import attention_paths
 from repro.optim import adamw_init, adamw_update, cosine_schedule, \
     wsd_schedule
 
@@ -36,12 +40,17 @@ def build_step(cfg, lr_fn):
     # place, so a full-width step holds one copy of them, not two
     @functools.partial(jax.jit, donate_argnums=(0,))
     def step(state, batch):
+        # runs once per trace: says which path each attention call took
+        before = collections.Counter(attention_paths())
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
         loss, grads = jax.value_and_grad(
             lambda p: train_loss(cfg, p, batch))(state["params"])
         lr = lr_fn(state["opt"]["step"])
         params, opt, gnorm = adamw_update(state["params"], grads,
                                           state["opt"], lr)
+        traced = collections.Counter(attention_paths()) - before
+        print(f"attention paths: {json.dumps(dict(sorted(traced.items())))}",
+              file=sys.stderr, flush=True)
         return ({"params": params, "opt": opt,
                  "step": state["step"] + 1},
                 {"loss": loss, "gnorm": gnorm, "lr": lr})

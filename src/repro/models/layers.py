@@ -4,16 +4,22 @@ Everything takes explicit param pytrees (dicts of arrays) so layers stack
 cleanly under ``lax.scan`` and shard cleanly under pjit.  Perf-critical
 ops (rmsnorm, attention, expert matmul, ssm scan) route through an
 ``impl`` registry so the Pallas kernels can be swapped in on TPU while
-the chunked-jnp references run everywhere (DESIGN.md §6).
+the chunked-jnp references run everywhere (DESIGN.md §6).  Attention
+without a KV cache takes JAX's fused Pallas flash kernels by itself
+where it can (``attention_path``): on one TPU, unwindowed, at lengths
+the kernels tile.
 """
 from __future__ import annotations
 
+import collections
+import threading
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.train_attention import block_sizes, train_attention
 from .attention import decode_attention
 
 # ---------------------------------------------------------------------------
@@ -146,6 +152,61 @@ def default_mrope_positions(batch: int, seq: int) -> jax.Array:
 # attention block (GQA, optional qkv bias / M-RoPE / window)
 # ---------------------------------------------------------------------------
 
+_ATTN_PATHS: collections.Counter = collections.Counter()
+_ATTN_PATHS_LOCK = threading.Lock()
+
+
+def _devices_spanned() -> int:
+    """Over how many devices the step being traced may run: the size of
+    the axis map's mesh, else of the context's mesh, else every device
+    (a jit given no mesh may still be given inputs sharded over them)."""
+    mesh = _AXIS_MAP.get("mesh")
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            return jax.device_count()
+    return mesh.size
+
+
+def attention_path(seq: int, *, kv_cache: bool, window) -> str:
+    """The path an attention call of ``attention_block`` at length
+    ``seq`` takes: "fused" where ``train_attention`` can run it, else
+    the first reason it cannot:
+
+      platform    the default backend is not a TPU
+      kv_cache    a decode step against a KV cache
+      registered  an impl is registered for "attention"
+      window      sliding-window attention
+      shape       a length that is not a multiple of 128
+      sharded     the step may run over more than one device: the TPU
+                  compiler does not partition a Pallas kernel
+
+    Tallied at trace time under the answer (``attention_paths``)."""
+    if jax.default_backend() != "tpu":
+        path = "platform"
+    elif kv_cache:
+        path = "kv_cache"
+    elif "attention" in _IMPLS:
+        path = "registered"
+    elif window is not None:
+        path = "window"
+    elif block_sizes(seq) is None:
+        path = "shape"
+    elif _devices_spanned() > 1:
+        path = "sharded"
+    else:
+        path = "fused"
+    with _ATTN_PATHS_LOCK:
+        _ATTN_PATHS[path] += 1
+    return path
+
+
+def attention_paths() -> dict:
+    """Attention calls traced in this process so far, by path."""
+    with _ATTN_PATHS_LOCK:
+        return dict(_ATTN_PATHS)
+
+
 def init_attn(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
               qkv_bias: bool, dtype) -> dict:
     k1, k2, k3, k4 = jax.random.split(key, 4)
@@ -207,6 +268,7 @@ def attention_block(p, x, cfg, *, positions=None, mrope_positions=None,
     elif cfg.rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
+    path = attention_path(s, kv_cache=kv_cache is not None, window=window)
     new_cache = None
     if kv_cache is not None:
         ck, cv = kv_cache
@@ -216,9 +278,12 @@ def attention_block(p, x, cfg, *, positions=None, mrope_positions=None,
                                           (0, 0, cache_len, 0))
         new_cache = (ck, cv)
         out = decode_attention(q, ck, cv, cache_len + s, window=window)
+    elif path == "fused":
+        out = train_attention(q, k, v, causal=causal)
     else:
-        # flash_attention_ref: linear-memory fwd AND bwd (custom VJP);
-        # the Pallas kernel substitutes via the impl registry on TPU
+        # off the fused path (attention_path names why): the impl
+        # registered for "attention", else flash_attention_ref, the jnp
+        # flash attention with a linear-memory fwd AND bwd (custom VJP)
         from .attention import flash_attention_ref
         attn = get_impl("attention", flash_attention_ref)
         kw = ({"unroll": True}

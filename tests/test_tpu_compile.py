@@ -9,19 +9,27 @@ topology is described inside a fixture, never on import, so every test
 worker collects the same tests and only the one given this file loads
 the TPU library.
 """
+import collections
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention_fwd_pallas
 from repro.kernels.mamba_scan import mamba_scan_pallas
 from repro.kernels.moe_gmm import moe_gmm_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
+from repro.kernels.train_attention import train_attention
+from repro.models import init, train_loss
+from repro.models import layers as L
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
@@ -34,9 +42,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -59,6 +72,58 @@ def test_flash_attention_fwd(one_chip, b, hq, hkv, s, d):
         q, k, v, causal=True, interpret=False), one_chip,
         ((b, hq, s, d), BF16), ((b, hkv, s, d), BF16),
         ((b, hkv, s, d), BF16))
+
+
+@pytest.mark.parametrize("b,h,s,d", [
+    (4, 16, 2048, 64),          # qwen1.5-0.5b, seq 2048 x batch 4
+    (1, 36, 4096, 64),          # minicpm-2b, seq 4096 x batch 1
+])
+def test_train_attention_grad(one_chip, b, h, s, d):
+    # the training step's fused attention, forward and both backward
+    # kernels, at the blocks ``train_attention.block_sizes`` chooses
+    def loss(q, k, v):
+        out = train_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(F32))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    ((b, h, s, d), BF16), ((b, h, s, d), BF16),
+                    ((b, h, s, d), BF16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("mesh_seen_by", ["axis_map", "context", "none"])
+def test_train_step_over_four_chips(v5e_2x2, monkeypatch, mesh_seen_by):
+    # a training step whose batch is split over the four chips: the TPU
+    # compiler does not partition a Pallas kernel, so the step lowers
+    # only if its attention leaves the fused path (by "sharded"),
+    # whether the mesh is in the axis map, in the context, or only in
+    # the jit's shardings on a host of four chips
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: len(v5e_2x2.devices))
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(2, 2), ("data", "model"))
+    cfg = get_config("qwen1.5-0.5b").reduced(
+        n_layers=2, d_model=128, d_ff=256, vocab=256, n_heads=2,
+        n_kv_heads=2)
+    params = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, P())), params)
+    batch = {k: jax.ShapeDtypeStruct((4, 256), jnp.int32,
+                                     sharding=NamedSharding(mesh, P("data")))
+             for k in ("tokens", "labels")}
+    step = jax.jit(jax.grad(lambda p, b: train_loss(cfg, p, b)))
+    before = collections.Counter(L.attention_paths())
+    if mesh_seen_by == "axis_map":
+        L.set_axis_map({"dp": "data", "tp": "model", "mesh": mesh})
+    try:
+        if mesh_seen_by == "none":
+            lowered = step.lower(params, batch)
+        else:
+            with jax.set_mesh(mesh):
+                lowered = step.lower(params, batch)
+    finally:
+        L.set_axis_map(None)
+    assert set(collections.Counter(L.attention_paths()) - before) == {
+        "sharded"}
+    assert "tpu_custom_call" not in lowered.compile().as_text()
 
 
 def test_rmsnorm_rows_not_a_multiple_of_8(one_chip):
