@@ -14,11 +14,14 @@ wrapper stops the loop by raising ``WindowClosed``.
 Read for ``correct``: the loss of steps 1 to 3, the norm of the first
 gradient as AdamW gets it (its first moment after step 1 over 1 - b1),
 and the norm of the parameters' change over steps 1 to 3, as step 4
-receives them, each leaf against the plain reference (``gaps.py``).
+receives them, each leaf against the plain reference (``gaps.py``): the
+module ``reference/<name>.py`` that the configuration's ``reference``
+names.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import math
 import shutil
 import tempfile
@@ -28,7 +31,7 @@ import numpy as np
 
 import gaps
 import seedweights
-from reference import dense_lm, tokens as ref_tokens
+from reference import tokens as ref_tokens
 
 # steps before the window: 1 compiles, 1-3 are compared, 4 reads the
 # change, 5 runs clean
@@ -40,18 +43,18 @@ class WindowClosed(Exception):
     pass
 
 
+def reference_of(c: dict):
+    """The plain reference the configuration names."""
+    return importlib.import_module(f"reference.{c['reference']}")
+
+
 def program_config(c: dict):
     """The program's ArchConfig for the configuration file, checked
-    against the file's published keys."""
+    against the file's published keys: ``program_keys`` maps each field
+    of the program's config to the published key it must equal."""
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config(c["arch"]), **c["program"])
-    want = {"n_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
-            "n_heads": c["num_attention_heads"],
-            "n_kv_heads": c["num_key_value_heads"],
-            "head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
-            "vocab": c["vocab_size"], "qkv_bias": c["qkv_bias"],
-            "tie_embeddings": c["tie_word_embeddings"],
-            "rope_theta": c["rope_theta"], "dtype": c["torch_dtype"]}
+    want = {k: c[published] for k, published in c["program_keys"].items()}
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise ValueError(f"program config {got} is not the file's {want}")
@@ -143,7 +146,7 @@ def run(ctx) -> dict:
     cfg = program_config(c)
     avals = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
     if (seedweights.names_and_shapes(avals)
-            != seedweights.names_and_shapes(dense_lm.param_avals(c))):
+            != seedweights.names_and_shapes(reference_of(c).param_avals(c))):
         raise ValueError("the program's parameter tree is not the "
                          "reference's")
     step_fn = (ctx.make_step(cfg, c) if ctx.make_step
@@ -215,10 +218,10 @@ def reference_readings(c: dict, seed: int, batch: int, seq: int,
     the first clipped gradient's leaf norms, and the leaf norms of the
     parameters' change over the three steps."""
     import jax
-    avals = dense_lm.param_avals(c)
-    make_params = seedweights.maker(avals)
-    step = dense_lm.make_step(c, precision)
-    state = dense_lm.init_state(make_params(seed))
+    ref = reference_of(c)
+    make_params = seedweights.maker(ref.param_avals(c))
+    step = ref.make_step(c, precision)
+    state = ref.init_state(make_params(seed))
     losses, grad_norms = [], None
     b1 = c["optimizer"]["b1"]
     for i in range(COMPARED_STEPS):
@@ -287,7 +290,7 @@ def fault_step(mode: str):
     if mode == "program":
         return None
     if mode == "fp8":
-        return lambda cfg, c: dense_lm.make_step(c, "fp8")
+        return lambda cfg, c: reference_of(c).make_step(c, "fp8")
     if mode not in FAULTS:
         raise ValueError(f"unknown mode {mode!r}")
 

@@ -3,11 +3,8 @@ span is open: the part of the token source's host work that the chip
 waits for, averaged over the cell's chips."""
 from __future__ import annotations
 
-from scopes import span_reading
+from scopes import idle_ms_per_step
 
 
 def read(r: dict):
-    sr = span_reading(r, "data.block")
-    if sr is None:
-        return None
-    return sr.idle_under_ns["data.block"] * 1e-6 / r["out"]["steps"]
+    return idle_ms_per_step(r, "data.block")

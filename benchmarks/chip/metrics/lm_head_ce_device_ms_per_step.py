@@ -13,5 +13,8 @@ from __future__ import annotations
 from scopes import scope_ms_per_step
 
 
+SCOPE = "lm_head_ce"
+
+
 def read(r: dict):
-    return scope_ms_per_step(r, "lm_head_ce")
+    return scope_ms_per_step(r, SCOPE)

@@ -5,11 +5,8 @@ loop dispatches the next step only after them; averaged over the cell's
 chips."""
 from __future__ import annotations
 
-from scopes import span_reading
+from scopes import idle_ms_per_step
 
 
 def read(r: dict):
-    sr = span_reading(r, "ft.metrics")
-    if sr is None:
-        return None
-    return sr.idle_under_ns["ft.metrics"] * 1e-6 / r["out"]["steps"]
+    return idle_ms_per_step(r, "ft.metrics")

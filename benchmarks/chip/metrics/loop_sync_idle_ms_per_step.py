@@ -5,11 +5,8 @@ the step to start or to hand back its outputs; averaged over the cell's
 chips."""
 from __future__ import annotations
 
-from scopes import span_reading
+from scopes import idle_ms_per_step
 
 
 def read(r: dict):
-    sr = span_reading(r, "ft.sync")
-    if sr is None:
-        return None
-    return sr.idle_under_ns["ft.sync"] * 1e-6 / r["out"]["steps"]
+    return idle_ms_per_step(r, "ft.sync")

@@ -7,5 +7,8 @@ from __future__ import annotations
 from scopes import scope_ms_per_step
 
 
+SCOPE = "mlp"
+
+
 def read(r: dict):
-    return scope_ms_per_step(r, "mlp")
+    return scope_ms_per_step(r, SCOPE)

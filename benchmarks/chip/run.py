@@ -14,7 +14,10 @@ produced against the plain reference.
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the metrics are the
 per-layer ones, read by ``metrics/<name>.py`` from the reduced trace
-(``tracereduce.py``) and the host spans.  The last line of standard
+(``tracereduce.py``), the program's scopes, kernels and spans in it
+(``scopes.py``) and the benchmark's host spans.  A traced run keys the
+compile cache with the programs' metadata, so that the ops in its trace
+carry the program's own names.  The last line of standard
 output is one JSON object; the numbers compared for ``correct`` are
 also the last lines of standard error.  Off a TPU, with fewer chips than
 the cell asks for, on a device kind missing from ``peaks.json``, or
@@ -117,12 +120,13 @@ def end_to_end(bench: dict, cell, out: dict, ctx, peaks: dict) -> dict:
             if "workloads" not in m or cell.name in m["workloads"]}
 
 
-def per_layer(bench: dict, cell, out: dict, ctx, reduced) -> dict:
+def per_layer(bench: dict, cell, out: dict, ctx, reduced, scoped,
+              peaks: dict) -> dict:
     from cellspec import load_plugin
     reported = {m["name"] for m in bench["end_to_end"]
                 if "workloads" not in m or cell.name in m["workloads"]}
     readings = {"cell": cell, "out": out, "spans": ctx.spans,
-                "reduced": reduced}
+                "reduced": reduced, "scopes": scoped, "peaks": peaks}
     metrics = {}
     for m in bench["per_layer"]:
         if "workloads" in m:
@@ -187,6 +191,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # without it a traced run may load a program compiled with other op
+    # names, and no op in its trace carries a scope
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      trace)
     try:
         devs, peaks = devices if devices is not None else device_check(cell)
     except (BenchError, SpecError) as e:
@@ -201,13 +209,15 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     out["window_host"] = out["window"]
     out["window"] = (out["window"][0] - T_START, out["window"][1] - T_START)
     if trace:
+        import scopes
         import tracereduce
         path = next(pathlib.Path(ctx.trace_dir).rglob("*.xplane.pb"))
         tr = tracereduce.load(str(path), tracereduce.GAP_LABELS
                               + (tracereduce.WINDOW_SPAN,))
         reduced = tracereduce.reduce(tr)
+        scoped = scopes.read(str(path))
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
-        metrics = per_layer(bench, cell, out, ctx, reduced)
+        metrics = per_layer(bench, cell, out, ctx, reduced, scoped, peaks)
     else:
         reduced = None
         metrics = end_to_end(bench, cell, out, ctx, peaks)

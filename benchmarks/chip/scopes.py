@@ -1,36 +1,47 @@
-"""The program's own layer names in a profiler trace: device scopes and
-host spans.
+"""The program's own layer names in a profiler trace: device scopes,
+kernel names and host spans.
 
 The program names its layers with ``jax.named_scope`` (device ops) and
-``jax.profiler.TraceAnnotation`` (host spans).  A scope reaches the
-trace as the ``tf_op`` stat of each ``XLA Ops`` event's metadata: the
-op's name path, e.g. ``jit(step)/transpose(jvp())/while/body/
-closed_call/checkpoint/attn/dot_general:``.  ``jax.profiler.ProfileData``
-does not expose event-metadata stats, so ``tf_ops`` decodes them from
-the ``.xplane.pb``'s protobuf wire format (``XSpace`` -> ``XPlane``
-``event_metadata`` and ``stat_metadata`` -> ``XStat``) and the events
-are joined to them by their name, the op's HLO text.
+``jax.profiler.TraceAnnotation`` (host spans), and its kernels by their
+own names.  A scope reaches the trace as the ``tf_op`` stat of each
+``XLA Ops`` event's metadata: the op's name path, e.g. ``jit(step)/
+transpose(jvp())/while/body/closed_call/checkpoint/attn/dot_general:``.
+``jax.profiler.ProfileData`` does not expose event-metadata stats, so
+``tf_ops`` decodes them from the ``.xplane.pb``'s protobuf wire format
+(``XSpace`` -> ``XPlane`` ``event_metadata`` and ``stat_metadata`` ->
+``XStat``) and the events are joined to them by their name, the op's
+HLO text.
 
-Each op belongs to the innermost scope on its path, matched as a whole
-path component once the transforms that wrap it are stripped
-(``transpose(jvp(lm_head_ce))`` is ``lm_head_ce``; ``attn_bias`` is not
-``attn``).  Container ops (``while``, ``call``, ``conditional``) belong
-to no scope, since their bodies' ops have events of their own.  Over the
-``window`` span, averaged over the chips: each scope's busy union, the
-busy time no scoped op covers, and the device's idle time under each of
-the program's host spans.
+Each op keeps its scope path: the components of its name path, with the
+transforms that wrap them stripped (``transpose(jvp(lm_head_ce))`` is
+``lm_head_ce``) and the primitive, the last component, left off.  An op
+is under a scope when the scope's name is one of those components, as a
+whole (``attn_bias`` is not ``attn``); an op under a scope nested in
+another is under both.  Container ops (``while``, ``call``,
+``conditional``) are under no scope, since their bodies' ops have events
+of their own.
+
+A run's reading (``ScopeReading``) answers, over the ``window`` span and
+averaged over the chips: the busy union of the ops under any scope name,
+or of the ops whose instruction names match a pattern (a kernel's), the
+busy time that no op under a given set of scopes covers, and the
+device's idle time under any host span.  It names no scope, kernel or
+span itself: the readers in ``metrics/`` do, so a reader of a new scope
+or a new kernel's roofline is a file of its own.
+
+The program's scopes, which ``unscoped_device_share`` leaves out, are
+named in that reader alone (its ``SCOPES``), so that the metric changes
+only with its own file.
 """
 from __future__ import annotations
 
 import re
 import sys
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import tracereduce as tr
+from cellspec import load_plugin
 
-SCOPES = ("attn", "mlp", "lm_head_ce", "adamw")
-# the program's host spans, in ``Supervisor.run`` and ``TokenLoader``
-PROGRAM_SPANS = ("train_step", "data.block", "ft.sync", "ft.metrics")
 _WRAPPED = re.compile(r"[A-Za-z_][\w.]*\((.*)\)")
 
 
@@ -127,29 +138,30 @@ def tf_ops(path: str) -> dict[int, dict[str, str]]:
 
 
 # --------------------------------------------------------------------------
-# scope matching
+# scope paths
 # --------------------------------------------------------------------------
 
-def scope_of(tf_op: str, scopes=SCOPES):
-    """The innermost of ``scopes`` on the op's name path, or None."""
+def scope_path(tf_op: str) -> tuple[str, ...]:
+    """The components of the op's name path, each stripped of the
+    transforms that wrap it, without the primitive."""
     path = tf_op.rpartition(":")[0] if ":" in tf_op else tf_op
-    found = None
-    for c in path.split("/"):
+    out = []
+    for c in path.split("/")[:-1]:
         while (m := _WRAPPED.fullmatch(c)):
             c = m.group(1)
-        if c in scopes:
-            found = c
-    return found
+        if c:
+            out.append(c)
+    return tuple(out)
 
 
 # --------------------------------------------------------------------------
-# the reduction
+# the reading
 # --------------------------------------------------------------------------
 
 @dataclass
 class ScopedOp:
-    scope: str | None   # None: no scope, or a container
-    opcode: str
+    name: str           # HLO instruction name, e.g. "flash_attention.15"
+    path: tuple         # scope path; () for none, or a container
     start: float        # ns
     end: float          # ns
 
@@ -157,28 +169,14 @@ class ScopedOp:
 @dataclass
 class ScopeTrace:
     devices: dict       # device index -> [ScopedOp] of the XLA Ops line
-    spans: list         # (name, start_ns, end_ns) host spans
+    spans: list         # (name, start_ns, end_ns) host events
 
 
-@dataclass
-class ScopeReading:
-    window_ns: float
-    n_devices: int
-    n_scoped_ops: int   # op events in the window under some scope
-    busy_ns: float      # the union of all ops, mean over devices
-    scope_busy_ns: dict = field(default_factory=dict)  # mean over devices
-    unscoped_ns: float = 0.0  # busy time under no scoped op
-    span_ns: dict = field(default_factory=dict)     # total, in the window
-    span_count: dict = field(default_factory=dict)  # started in the window
-    idle_under_ns: dict = field(default_factory=dict)  # mean over devices
-
-
-def load(path: str, span_names=PROGRAM_SPANS) -> ScopeTrace:
-    """The ops of each TPU device plane with their scopes, and the host
-    spans named in ``span_names`` and ``window``."""
+def load(path: str) -> ScopeTrace:
+    """The ops of each TPU device plane with their scope paths, and every
+    event of the host plane."""
     from jax.profiler import ProfileData
     names = tf_ops(path)
-    wanted = set(span_names) | {tr.WINDOW_SPAN}
     devices, spans = {}, []
     for plane in ProfileData.from_file(str(path)).planes:
         m = tr.DEVICE_PLANE.match(plane.name)
@@ -190,17 +188,16 @@ def load(path: str, span_names=PROGRAM_SPANS) -> ScopeTrace:
                 if line.name != "XLA Ops":
                     continue
                 for e in line.events:
-                    _, opcode = tr.parse_hlo_event(e.name)
-                    scope = (None if opcode in tr.CONTAINERS
-                             else scope_of(tf.get(e.name, "")))
-                    ops.append(ScopedOp(scope, opcode, e.start_ns,
+                    name, opcode = tr.parse_hlo_event(e.name)
+                    path = (() if opcode in tr.CONTAINERS
+                            else scope_path(tf.get(e.name, "")))
+                    ops.append(ScopedOp(name, path, e.start_ns,
                                         e.start_ns + e.duration_ns))
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
-                    if e.name in wanted:
-                        spans.append((e.name, e.start_ns,
-                                      e.start_ns + e.duration_ns))
+                    spans.append((e.name, e.start_ns,
+                                  e.start_ns + e.duration_ns))
     return ScopeTrace(devices=devices, spans=spans)
 
 
@@ -210,52 +207,83 @@ def intersect(a, b) -> list:
     return tr.subtract(a, tr.subtract(a, b))
 
 
-def reduce(trace: ScopeTrace, window=None, scopes=SCOPES,
-           span_names=PROGRAM_SPANS) -> ScopeReading:
-    lo, hi = window if window is not None else tr.window_of(trace)
-    if not trace.devices:
-        raise ValueError("the trace holds no TPU device plane")
-    n = len(trace.devices)
-    in_window = [(name, s, e) for name, s, e in trace.spans
-                 if name in span_names and lo <= s < hi]
-    span_unions = {name: tr.union(tr.clip(
-        [(s, e) for nm, s, e in in_window if nm == name], lo, hi))
-        for name in span_names}
-    busy_sum = unscoped_sum = 0.0
-    scope_sum = {sc: 0.0 for sc in scopes}
-    idle_sum = {name: 0.0 for name in span_names}
-    n_scoped = 0
-    for ops in trace.devices.values():
-        ops = [o for o in ops if o.end > lo and o.start < hi]
-        busy = tr.union(tr.clip([(o.start, o.end) for o in ops], lo, hi))
-        busy_sum += tr.total(busy)
-        scoped = [o for o in ops if o.scope in scopes]
-        n_scoped += len(scoped)
-        for sc in scopes:
-            scope_sum[sc] += tr.total(tr.union(tr.clip(
-                [(o.start, o.end) for o in scoped if o.scope == sc],
-                lo, hi)))
-        covered = tr.union(tr.clip([(o.start, o.end) for o in scoped],
-                                   lo, hi))
-        unscoped_sum += tr.total(tr.subtract(busy, covered))
-        idle = tr.gaps(busy, lo, hi)
-        for name in span_names:
-            idle_sum[name] += tr.total(intersect(idle, span_unions[name]))
-    return ScopeReading(
-        window_ns=hi - lo, n_devices=n, n_scoped_ops=n_scoped,
-        busy_ns=busy_sum / n,
-        scope_busy_ns={sc: v / n for sc, v in scope_sum.items()},
-        unscoped_ns=unscoped_sum / n,
-        span_ns={name: sum(e - s for nm, s, e in in_window if nm == name)
-                 for name in span_names},
-        span_count={name: sum(nm == name for nm, _, _ in in_window)
-                    for name in span_names},
-        idle_under_ns={k: v / n for k, v in idle_sum.items()})
+class ScopeReading:
+    """A trace over its ``window`` span (or ``window``): each device's
+    ops in it, and the host spans that start in it.  Times are in ns,
+    averaged over the devices."""
+
+    def __init__(self, trace: ScopeTrace, window=None) -> None:
+        lo, hi = window if window is not None else tr.window_of(trace)
+        if not trace.devices:
+            raise ValueError("the trace holds no TPU device plane")
+        self.lo, self.hi = lo, hi
+        self.window_ns = hi - lo
+        self.n_devices = len(trace.devices)
+        self._ops = [[o for o in ops if o.end > lo and o.start < hi]
+                     for ops in trace.devices.values()]
+        self._busy = [self._union(ops) for ops in self._ops]
+        self.busy_ns = self._mean(tr.total(b) for b in self._busy)
+        self._spans = [(n, s, e) for n, s, e in trace.spans
+                       if lo <= s < hi]
+
+    def _union(self, ops) -> list:
+        return tr.union(tr.clip([(o.start, o.end) for o in ops],
+                                self.lo, self.hi))
+
+    def _mean(self, values) -> float:
+        return sum(values) / self.n_devices
+
+    def _under(self, scopes):
+        names = set(scopes)
+        return lambda o: not names.isdisjoint(o.path)
+
+    def n_ops_under(self, scopes) -> int:
+        """Op events in the window under any of ``scopes``."""
+        under = self._under(scopes)
+        return sum(under(o) for ops in self._ops for o in ops)
+
+    def busy_under(self, scopes) -> float:
+        """The busy union of the ops under any of ``scopes``."""
+        under = self._under(scopes)
+        return self._mean(tr.total(self._union(o for o in ops if under(o)))
+                          for ops in self._ops)
+
+    def busy_matching(self, pattern: str) -> float:
+        """The busy union of the ops whose instruction names match the
+        regular expression ``pattern`` as a whole."""
+        rx = re.compile(pattern)
+        return self._mean(tr.total(self._union(
+            o for o in ops if rx.fullmatch(o.name))) for ops in self._ops)
+
+    def unscoped_ns(self, scopes) -> float:
+        """Busy time that no op under any of ``scopes`` covers."""
+        under = self._under(scopes)
+        return self._mean(
+            tr.total(tr.subtract(busy, self._union(o for o in ops
+                                                   if under(o))))
+            for ops, busy in zip(self._ops, self._busy))
+
+    def span_count(self, name: str) -> int:
+        """``name`` spans that start in the window."""
+        return sum(n == name for n, _, _ in self._spans)
+
+    def span_ns(self, name: str) -> float:
+        """Total length of the ``name`` spans that start in the window."""
+        return sum(e - s for n, s, e in self._spans if n == name)
+
+    def idle_under_ns(self, name: str) -> float:
+        """Device idle time in the window while a ``name`` span that
+        starts in it is open."""
+        spans = tr.union(tr.clip([(s, e) for n, s, e in self._spans
+                                  if n == name], self.lo, self.hi))
+        return self._mean(
+            tr.total(intersect(tr.gaps(busy, self.lo, self.hi), spans))
+            for busy in self._busy)
 
 
 def read(path: str) -> ScopeReading:
     """The reading of the trace at ``path`` over its ``window`` span."""
-    return reduce(load(path))
+    return ScopeReading(load(path))
 
 
 # --------------------------------------------------------------------------
@@ -270,26 +298,26 @@ def _reading(r: dict):
     return sr
 
 
-def scoped_reading(r: dict):
-    """The run's scope reading where some op in the window carries a
-    scope; else None, and why on standard error."""
+def scoped_reading(r: dict, scopes):
+    """The run's scope reading where some op in the window is under one
+    of ``scopes``; else None, and why on standard error."""
     sr = _reading(r)
     if sr is None:
         return None
-    if sr.n_scoped_ops == 0:
-        print("scopes: no op in the window carries a scope: the program "
-              "came from a compile cache keyed without its metadata, or "
-              "lost it", file=sys.stderr)
+    if sr.n_ops_under(scopes) == 0:
+        print(f"scopes: no op in the window is under {sorted(scopes)}: the "
+              "program has no such scope, or came from a compile cache "
+              "keyed without its metadata", file=sys.stderr)
         return None
     return sr
 
 
 def scope_ms_per_step(r: dict, scope: str):
     """Busy time of the ops under ``scope`` per measured step, in ms."""
-    sr = scoped_reading(r)
+    sr = scoped_reading(r, (scope,))
     if sr is None:
         return None
-    return sr.scope_busy_ns[scope] * 1e-6 / r["out"]["steps"]
+    return sr.busy_under((scope,)) * 1e-6 / r["out"]["steps"]
 
 
 def span_reading(r: dict, name: str):
@@ -300,8 +328,42 @@ def span_reading(r: dict, name: str):
     if sr is None:
         return None
     steps = r["out"]["steps"]
-    if abs(sr.span_count[name] - steps) > 1:
-        print(f"scopes: {sr.span_count[name]} '{name}' spans in a window "
+    if abs(sr.span_count(name) - steps) > 1:
+        print(f"scopes: {sr.span_count(name)} '{name}' spans in a window "
               f"of {steps} steps", file=sys.stderr)
         return None
     return sr
+
+
+def idle_ms_per_step(r: dict, name: str):
+    """Device idle time per measured step while a ``name`` span is
+    open, in ms."""
+    sr = span_reading(r, name)
+    if sr is None:
+        return None
+    return sr.idle_under_ns(name) * 1e-6 / r["out"]["steps"]
+
+
+def roofline_share(r: dict, kernel: str):
+    """The share (%) of its roofline that ``kernel`` reaches: the least
+    time the cell's chips could take for the kernel's work in the
+    window, the larger of its operations over the peak FLOP/s and its
+    bytes over the peak bytes/s (``rooflines/<kernel>.py``, a step's
+    work from the configuration and traffic, shared evenly by the
+    chips), over the busy union of the kernel's device ops (``OPS``, a
+    pattern of instruction names).  None where no such op ran."""
+    sr = _reading(r)
+    if sr is None:
+        return None
+    work = load_plugin("rooflines", kernel)
+    busy_ns = sr.busy_matching(work.OPS)
+    if busy_ns <= 0:
+        print(f"scopes: no device op in the window matches {kernel}'s "
+              f"{work.OPS!r}", file=sys.stderr)
+        return None
+    cell, peaks = r["cell"], r["peaks"]
+    per_chip = r["out"]["steps"] / cell.chips
+    least_s = per_chip * max(
+        work.flops(cell.config, cell.traffic) / peaks["bf16_flops_per_s"],
+        work.bytes(cell.config, cell.traffic) / peaks["hbm_bytes_per_s"])
+    return 100.0 * least_s / (busy_ns * 1e-9)

@@ -28,6 +28,8 @@ import tempfile
 HERE = pathlib.Path(__file__).resolve().parent
 REPO = HERE.parents[2]
 STEPS = 5
+# the program's host spans, in ``Supervisor.run`` and ``TokenLoader``
+SPANS = ("train_step", "data.block", "ft.sync", "ft.metrics")
 
 
 def main(out: str) -> int:
@@ -37,6 +39,7 @@ def main(out: str) -> int:
     import jax.numpy as jnp
     from jax.profiler import TraceAnnotation
 
+    import cellspec
     import scopes
     from repro.checkpoint import CheckpointManager
     from repro.configs import get_config
@@ -81,8 +84,12 @@ def main(out: str) -> int:
     dest = out_dir / "scopes_one_chip.xplane.pb.xz"
     dest.write_bytes(lzma.compress(path.read_bytes()))
     shutil.rmtree(trace_dir, ignore_errors=True)
-    print(json.dumps({"bytes": dest.stat().st_size,
-                      "reading": dataclasses.asdict(red)}))
+    program = cellspec.load_plugin("metrics", "unscoped_device_share").SCOPES
+    print(json.dumps({
+        "bytes": dest.stat().st_size, "busy_ns": red.busy_ns,
+        "scope_busy_ns": {sc: red.busy_under((sc,)) for sc in program},
+        "unscoped_ns": red.unscoped_ns(program),
+        "span_count": {n: red.span_count(n) for n in SPANS}}))
     return 0
 
 

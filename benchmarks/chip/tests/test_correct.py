@@ -4,8 +4,9 @@ skipped, the rest of ``run.run_cell`` runs with the cell's own lane and
 with limits read at this size.  The program passes; the control (the
 plain reference in the program's place, its matmuls in float8) and each
 fault the cell can have come out not correct.  The Piper-IR lane is
-driven too, on the four-chip cell that waits for its readings on the
-chip (``PERF.md``).
+driven too, on four host devices, on the four-chip cell that waits for
+the IR lane to run the model's own layers (``PERF.md``).  A traced run
+hands the per-layer readers the program's scopes.
 
   PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest benchmarks/chip/tests
 """
@@ -41,6 +42,7 @@ IR_STRATEGY = {
          "kind": "zero", "reduce_stream": "dp", "stage": 3}],
     "mesh": {"axes": [["pp", 2], ["dp", 2]]}, "schema": 3}
 CELLS = [w["name"] for w in BENCH["workloads"]] + [IR_CELL]
+FIXTURE = HERE / "fixtures" / "scopes_one_chip.xplane.pb.xz"
 
 SMALL = {"hidden_size": 64, "intermediate_size": 128,
          "num_hidden_layers": 2, "num_attention_heads": 4,
@@ -135,6 +137,64 @@ def test_program_correct_control_and_faults_not(name):
     assert prog["attempted"] > 0 and prog["failed"] == 0
     for mode, res in results.items():
         assert not res["correct"], (mode, res["checks"])
+
+
+def test_traced_run_hands_readers_the_scope_reading(monkeypatch, tmp_path):
+    """A traced run on the CPU, its profiler's trace replaced by the one
+    recorded on the chip: ``run_cell`` keys the compile cache with the
+    programs' metadata and hands every reader the trace's scope
+    reading."""
+    import lzma
+
+    import jax
+
+    import scopes
+    recorded = tmp_path / "scopes_one_chip.xplane.pb"
+    recorded.write_bytes(lzma.decompress(FIXTURE.read_bytes()))
+
+    def start_trace(log_dir):
+        out = pathlib.Path(log_dir) / "plugins" / "profile" / "run"
+        out.mkdir(parents=True)
+        (out / recorded.name).write_bytes(recorded.read_bytes())
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    seen = {}
+    load_plugin = cellspec.load_plugin
+
+    def recording(kind, name, *args):
+        mod = load_plugin(kind, name, *args)
+        if kind != "metrics":
+            return mod
+
+        class Reader:
+            @staticmethod
+            def read(r):
+                seen[name] = r
+                return mod.read(r)
+        return Reader
+
+    monkeypatch.setattr(cellspec, "load_plugin", recording)
+    cell = small_cell(BENCH["workloads"][0]["name"])
+    lane = cellspec.load_plugin("lanes", cell.lane)
+    try:
+        res = bench_run.run_cell(
+            cell, 2**33 + 11, 0.5, True, make_step=lane.fault_step("program"),
+            devices=(jax.devices()[:1], cellspec.peaks_for("TPU v5 lite")))
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+    want = {m["name"] for m in BENCH["per_layer"]
+            if cell.name in m.get("workloads", CELLS)}
+    assert set(seen) == want
+    sr = seen["attn_device_ms_per_step"]["scopes"]
+    assert isinstance(sr, scopes.ScopeReading)
+    assert all(r["scopes"] is sr for r in seen.values())
+    assert sr.busy_ns == scopes.read(str(recorded)).busy_ns > 0
+    assert res["correct"]
+    assert "attn_device_ms_per_step" in res["metrics"]
+    assert res["device"]["busy_s"] == pytest.approx(sr.busy_ns * 1e-9)
 
 
 if __name__ == "__main__":

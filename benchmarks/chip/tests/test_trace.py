@@ -117,3 +117,28 @@ def test_recorded_trace():
     # the host's loader work is what the device waits for
     assert red.idle_by_label[0][0] in ("loader", "dispatch")
     assert red.top_ops[0][1] > 0
+
+
+def test_step_mfu_is_model_flops_over_busy_time():
+    import cellspec
+    chip = HERE.parent
+    cell = cellspec.load_cell(chip.parents[1] / "BENCHMARK.json",
+                              "qwen1.5-0.5b.train-s2048-b4")
+    peaks = cellspec.peaks_for("TPU v5 lite")
+    # 10 steps of 8192 tokens at 3.388 GFLOP a token in 5 s of device
+    # busy time: 277.6 TFLOP over 5 s x 197 TFLOP/s
+    red = tr.Reduced(window_ns=6e9, n_devices=1, busy_ns=5e9,
+                     exposed_collective_ns=0, top_ops=[], idle_by_label=[])
+    out = {"tokens": 10 * 8192, "steps": 10, "lane_info": {}}
+    value = cellspec.load_plugin("metrics", "step_mfu").read(
+        {"reduced": red, "out": out, "cell": cell, "peaks": peaks})
+    fpt = cellspec.flops_per_token(cell, {})
+    assert value == pytest.approx(100 * fpt * 81920 / (5 * 197e12))
+    assert value == pytest.approx(28.18, abs=0.01)
+    # four chips each busy 5 s do four times the work in the same share
+    red4 = tr.Reduced(window_ns=6e9, n_devices=4, busy_ns=5e9,
+                      exposed_collective_ns=0, top_ops=[], idle_by_label=[])
+    out4 = dict(out, tokens=4 * out["tokens"])
+    assert cellspec.load_plugin("metrics", "step_mfu").read(
+        {"reduced": red4, "out": out4, "cell": cell, "peaks": peaks}) == \
+        pytest.approx(value)
