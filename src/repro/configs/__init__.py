@@ -12,6 +12,7 @@ ARCHS = [
     "whisper-large-v3", "qwen2-vl-7b", "zamba2-2.7b",
     # the paper's own evaluation models
     "qwen3-1b", "qwen3-9b",
+    "moonlight-16b-a3b",
 ]
 
 # the ten assigned-architecture cells for the dry-run table

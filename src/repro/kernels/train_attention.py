@@ -1,15 +1,24 @@
 """The training step's attention on the TPU: JAX's fused Pallas flash
 kernels (``jax.experimental.pallas.ops.tpu.flash_attention``), forward
 and a custom VJP of dK/dV and dQ kernels, which skip the key blocks
-above the causal diagonal.
+above the causal diagonal.  Latent attention (MLA), whose values are
+narrower than its queries and keys (d_v 128 against d_qk 192), takes
+JAX's splash-attention kernels instead
+(``jax.experimental.pallas.ops.tpu.splash_attention``), which the flash
+kernels refuse it: forward, dQ and dK/dV, causal blocks skipped.
 
 ``models.layers.attention_block`` decides when to call it.  This module
 imports nothing of the model, so the model can import it.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import flash_attention as jax_flash
+from jax.experimental.pallas.ops.tpu.splash_attention import \
+    splash_attention_kernel as splash
+from jax.experimental.pallas.ops.tpu.splash_attention import \
+    splash_attention_mask as splash_mask
 
 
 def block_sizes(seq: int):
@@ -40,3 +49,28 @@ def train_attention(q, k, v, *, causal: bool = True, window=None):
     return jax_flash.flash_attention(q, k, v, causal=causal,
                                      sm_scale=d ** -0.5,
                                      block_sizes=block_sizes(s))
+
+
+def _splash_kernel(heads: int, seq: int, block: int):
+    """The causal splash kernel for ``heads`` heads of ``seq`` rows, every
+    block ``block`` rows.  Built anew at each trace: it holds its block
+    masks as arrays of the trace it was made in."""
+    mask = splash_mask.MultiHeadMask(
+        [splash_mask.CausalMask((seq, seq)) for _ in range(heads)])
+    sizes = splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    return splash.make_splash_mha_single_device(mask, block_sizes=sizes)
+
+
+def latent_attention(q, k, v):
+    """Causal attention of a training step on one TPU where the values
+    are narrower than the queries and keys, as in MLA: q, k (B, H, S,
+    D_qk), v (B, H, S, D_v), S a multiple of 128.  Scores are scaled by
+    D_qk^-1/2, applied to q before the kernel, which takes no scale.
+    Returns (B, H, S, D_v)."""
+    _, h, s, d = q.shape
+    block = block_sizes(s).block_q
+    kernel = _splash_kernel(h, s, block)
+    return jax.vmap(kernel)((q * d ** -0.5).astype(q.dtype), k, v)

@@ -29,7 +29,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import SyntheticTokenSource, TokenLoader
 from repro.ft import FailureInjector, Supervisor
-from repro.models import init, train_loss
+from repro.models import init, train_loss_and_stats
 from repro.models.layers import attention_paths
 from repro.optim import adamw_init, adamw_update, cosine_schedule, \
     wsd_schedule
@@ -43,8 +43,10 @@ def build_step(cfg, lr_fn):
         # runs once per trace: says which path each attention call took
         before = collections.Counter(attention_paths())
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        loss, grads = jax.value_and_grad(
-            lambda p: train_loss(cfg, p, batch))(state["params"])
+        # stats: the held expert layers' load counters, {} without them
+        (loss, stats), grads = jax.value_and_grad(
+            lambda p: train_loss_and_stats(cfg, p, batch),
+            has_aux=True)(state["params"])
         lr = lr_fn(state["opt"]["step"])
         params, opt, gnorm = adamw_update(state["params"], grads,
                                           state["opt"], lr)
@@ -53,7 +55,7 @@ def build_step(cfg, lr_fn):
               file=sys.stderr, flush=True)
         return ({"params": params, "opt": opt,
                  "step": state["step"] + 1},
-                {"loss": loss, "gnorm": gnorm, "lr": lr})
+                {"loss": loss, "gnorm": gnorm, "lr": lr, **stats})
     return step
 
 

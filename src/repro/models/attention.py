@@ -108,6 +108,8 @@ def chunked_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 
 # ---------------------------------------------------------------------------
 # flash attention (custom VJP): linear-memory forward AND backward.
+# Values may be narrower than queries and keys (MLA); scores are scaled
+# by the query's width.
 # The plain chunked_attention above is mathematically identical but its
 # scan saves per-block probabilities for autodiff — O(Sq*Skv) residuals.
 # This version saves only (out, logsumexp) and recomputes scores per
@@ -127,7 +129,7 @@ def flash_attention(q, k, v, causal: bool = True, q_offset: int = 0,
 def _flash_fwd_impl(q, k, v, causal, q_offset, window, block_kv,
                     unroll: bool = False):
     b, hq, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
+    hkv, skv, dv = k.shape[1], k.shape[2], v.shape[-1]
     n_rep = hq // hkv
     scale = d ** -0.5
     nb = -(-skv // block_kv)
@@ -135,7 +137,7 @@ def _flash_fwd_impl(q, k, v, causal, q_offset, window, block_kv,
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else k
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else v
     kb = jnp.moveaxis(kp.reshape(b, hkv, nb, block_kv, d), 2, 0)
-    vb = jnp.moveaxis(vp.reshape(b, hkv, nb, block_kv, d), 2, 0)
+    vb = jnp.moveaxis(vp.reshape(b, hkv, nb, block_kv, dv), 2, 0)
     qpos = jnp.arange(sq) + q_offset
     q32 = (q * scale).astype(jnp.float32)
     kpos_all = jnp.arange(nb * block_kv).reshape(nb, block_kv)
@@ -158,7 +160,7 @@ def _flash_fwd_impl(q, k, v, causal, q_offset, window, block_kv,
 
     m0 = jnp.full((b, hq, sq), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((b, hq, sq), dtype=jnp.float32)
-    a0 = jnp.zeros((b, hq, sq, d), dtype=jnp.float32)
+    a0 = jnp.zeros((b, hq, sq, dv), dtype=jnp.float32)
     (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kb, vb, kpos_all),
                                   unroll=unroll)
     out = (acc / jnp.maximum(l[..., None], 1e-30)).astype(q.dtype)
@@ -188,7 +190,7 @@ def _flash_fwd(q, k, v, causal, q_offset, window, block_kv,
 def _flash_bwd(causal, q_offset, window, block_kv, unroll, res, dout):
     q, k, v, out, lse = res
     b, hq, sq, d = q.shape
-    hkv, skv = k.shape[1], k.shape[2]
+    hkv, skv, dv = k.shape[1], k.shape[2], v.shape[-1]
     n_rep = hq // hkv
     scale = d ** -0.5
     nb = -(-skv // block_kv)
@@ -196,7 +198,7 @@ def _flash_bwd(causal, q_offset, window, block_kv, unroll, res, dout):
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else k
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else v
     kb = jnp.moveaxis(kp.reshape(b, hkv, nb, block_kv, d), 2, 0)
-    vb = jnp.moveaxis(vp.reshape(b, hkv, nb, block_kv, d), 2, 0)
+    vb = jnp.moveaxis(vp.reshape(b, hkv, nb, block_kv, dv), 2, 0)
     kpos_all = jnp.arange(nb * block_kv).reshape(nb, block_kv)
     qpos = jnp.arange(sq) + q_offset
     q32 = (q * scale).astype(jnp.float32)
@@ -219,17 +221,17 @@ def _flash_bwd(causal, q_offset, window, block_kv, unroll, res, dout):
         dk_r = jnp.einsum("bhqk,bhqd->bhkd", ds, q32)
         # fold grouped heads back to kv heads
         dk_g = dk_r.reshape(b, hkv, n_rep, block_kv, d).sum(axis=2)
-        dv_g = dv_r.reshape(b, hkv, n_rep, block_kv, d).sum(axis=2)
+        dv_g = dv_r.reshape(b, hkv, n_rep, block_kv, dv).sum(axis=2)
         return dq_acc + dq_blk, (dk_g, dv_g)
 
     dq0 = jnp.zeros((b, hq, sq, d), jnp.float32)
     dq, (dk_b, dv_b) = jax.lax.scan(step, dq0, (kb, vb, kpos_all),
                                     unroll=unroll)
     dk = jnp.moveaxis(dk_b, 0, 2).reshape(b, hkv, nb * block_kv, d)
-    dv = jnp.moveaxis(dv_b, 0, 2).reshape(b, hkv, nb * block_kv, d)
+    dvv = jnp.moveaxis(dv_b, 0, 2).reshape(b, hkv, nb * block_kv, dv)
     if pad:
-        dk, dv = dk[:, :, :skv], dv[:, :, :skv]
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
+        dk, dvv = dk[:, :, :skv], dvv[:, :, :skv]
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dvv.astype(v.dtype))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

@@ -12,6 +12,7 @@ the kernels tile.
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 from typing import Any, Callable, Optional
 
@@ -19,7 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.train_attention import block_sizes, train_attention
+from ..kernels.train_attention import block_sizes, latent_attention, \
+    train_attention
+from ..kernels.train_gmm import grouped_matmul, tiling as gmm_tiling
 from .attention import decode_attention
 
 # ---------------------------------------------------------------------------
@@ -168,10 +171,11 @@ def _devices_spanned() -> int:
     return mesh.size
 
 
-def attention_path(seq: int, *, kv_cache: bool, window) -> str:
+def attention_path(seq: int, *, kv_cache: bool, window,
+                   latent: bool = False) -> str:
     """The path an attention call of ``attention_block`` at length
-    ``seq`` takes: "fused" where ``train_attention`` can run it, else
-    the first reason it cannot:
+    ``seq`` takes: "fused" where ``train_attention`` (``latent_attention``
+    for MLA) can run it, else the first reason it cannot:
 
       platform    the default backend is not a TPU
       kv_cache    a decode step against a KV cache
@@ -181,7 +185,8 @@ def attention_path(seq: int, *, kv_cache: bool, window) -> str:
       sharded     the step may run over more than one device: the TPU
                   compiler does not partition a Pallas kernel
 
-    Tallied at trace time under the answer (``attention_paths``)."""
+    Tallied at trace time under the answer (``attention_paths``), an
+    MLA call's as "mla_<answer>"."""
     if jax.default_backend() != "tpu":
         path = "platform"
     elif kv_cache:
@@ -197,7 +202,7 @@ def attention_path(seq: int, *, kv_cache: bool, window) -> str:
     else:
         path = "fused"
     with _ATTN_PATHS_LOCK:
-        _ATTN_PATHS[path] += 1
+        _ATTN_PATHS["mla_" + path if latent else path] += 1
     return path
 
 
@@ -228,11 +233,47 @@ def init_attn(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
     return p
 
 
+def _attend(q, k, v, cfg, *, kv_cache, cache_len, causal, window=None,
+            latent=False):
+    """Attention of q (B, Hq, S, D) over k and v, on the path
+    ``attention_path`` gives: against the KV cache (updated at
+    ``cache_len``) when decoding, the fused kernels, or the fallback.
+    Returns (out, new_kv), new_kv None without a cache."""
+    s = q.shape[2]
+    path = attention_path(s, kv_cache=kv_cache is not None, window=window,
+                          latent=latent)
+    if kv_cache is not None:
+        ck, cv = kv_cache
+        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                          (0, 0, cache_len, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                          (0, 0, cache_len, 0))
+        return decode_attention(q, ck, cv, cache_len + s,
+                                window=window), (ck, cv)
+    if path == "fused":
+        if latent:
+            return latent_attention(q, k, v), None
+        return train_attention(q, k, v, causal=causal), None
+    # off the fused path (attention_path names why): the impl registered
+    # for "attention", else flash_attention_ref, the jnp flash attention
+    # with a linear-memory fwd AND bwd (custom VJP)
+    from .attention import flash_attention_ref
+    attn = get_impl("attention", flash_attention_ref)
+    kw = ({"unroll": True}
+          if getattr(cfg, "unroll_scans", False)
+          and attn is flash_attention_ref else {})
+    return attn(q, k, v, causal=causal, window=window, **kw), None
+
+
 def attention_block(p, x, cfg, *, positions=None, mrope_positions=None,
                     kv_cache=None, cache_len=None, causal=True,
                     window=None):
     """Returns (out, new_kv) where kv_cache is (k, v) of shape
-    (B, Hkv, Smax, D) when decoding, else None."""
+    (B, Hkv, Smax, D) when decoding, else None.  A config with a latent
+    rank runs ``mla_block``."""
+    if cfg.kv_lora_rank:
+        return mla_block(p, x, cfg, kv_cache=kv_cache, cache_len=cache_len,
+                         causal=causal)
     b, s, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = x @ p["wq"]
@@ -268,29 +309,57 @@ def attention_block(p, x, cfg, *, positions=None, mrope_positions=None,
     elif cfg.rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    path = attention_path(s, kv_cache=kv_cache is not None, window=window)
-    new_cache = None
-    if kv_cache is not None:
-        ck, cv = kv_cache
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, 0, cache_len, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, 0, cache_len, 0))
-        new_cache = (ck, cv)
-        out = decode_attention(q, ck, cv, cache_len + s, window=window)
-    elif path == "fused":
-        out = train_attention(q, k, v, causal=causal)
-    else:
-        # off the fused path (attention_path names why): the impl
-        # registered for "attention", else flash_attention_ref, the jnp
-        # flash attention with a linear-memory fwd AND bwd (custom VJP)
-        from .attention import flash_attention_ref
-        attn = get_impl("attention", flash_attention_ref)
-        kw = ({"unroll": True}
-              if getattr(cfg, "unroll_scans", False)
-              and attn is flash_attention_ref else {})
-        out = attn(q, k, v, causal=causal, window=window, **kw)
+    out, new_cache = _attend(q, k, v, cfg, kv_cache=kv_cache,
+                             cache_len=cache_len, causal=causal,
+                             window=window)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
+    return out @ p["wo"], new_cache
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3 MLA, no query compression)
+# ---------------------------------------------------------------------------
+
+def init_mla(key, d_model: int, n_heads: int, kv_lora_rank: int,
+             qk_nope: int, qk_rope: int, v_dim: int, dtype) -> dict:
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+
+    def mat(k, fan_in, fan_out):
+        return (jax.random.normal(k, (fan_in, fan_out)) * fan_in ** -0.5
+                ).astype(dtype)
+    return {"wq": mat(k1, d_model, n_heads * (qk_nope + qk_rope)),
+            "wkv_a": mat(k2, d_model, kv_lora_rank + qk_rope),
+            "kv_norm": jnp.ones((kv_lora_rank,), dtype),
+            "wkv_b": mat(k3, kv_lora_rank, n_heads * (qk_nope + v_dim)),
+            "wo": mat(k4, n_heads * v_dim, d_model)}
+
+
+def mla_block(p, x, cfg, *, kv_cache=None, cache_len=None, causal=True):
+    """Latent attention: q = x·W_q, heads of [nope | rope]; x·W_kva is
+    the latent c (``kv_lora_rank``) and one rope key all heads share; c,
+    normed, times W_kvb gives each head's nope key and value.  Rope turns
+    the rope dims alone; scores are scaled by (nope + rope)^-1/2.
+    Returns (out, new_kv) like ``attention_block``; the cache holds the
+    expanded per-head keys (B, H, Smax, nope + rope) and values."""
+    b, s, _ = x.shape
+    h, dn, dr, dv = (cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim)
+    r = cfg.kv_lora_rank
+    q = (x @ p["wq"]).reshape(b, s, h, dn + dr).transpose(0, 2, 1, 3)
+    kva = x @ p["wkv_a"]
+    c = rmsnorm(kva[..., :r], p["kv_norm"], cfg.rms_norm_eps)
+    kv = (c @ p["wkv_b"]).reshape(b, s, h, dn + dv).transpose(0, 2, 1, 3)
+    base = 0 if cache_len is None else cache_len
+    positions = jnp.arange(s) + base
+    q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    k_rope = apply_rope(kva[:, None, :, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_rope], -1)
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(k_rope, (b, h, s, dr))], -1)
+    v = kv[..., dn:]
+    out, new_cache = _attend(q, k, v, cfg, kv_cache=kv_cache,
+                             cache_len=cache_len, causal=causal, latent=True)
+    out = out.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
     return out @ p["wo"], new_cache
 
 
@@ -323,22 +392,29 @@ def mlp_block(p, x, act: str = "swiglu"):
 # ---------------------------------------------------------------------------
 
 def init_moe(key, d_model: int, d_expert: int, n_experts: int,
-             n_shared: int, act: str, dtype) -> dict:
+             n_shared: int, act: str, dtype, n_held: int = 0,
+             router_bias: bool = False) -> dict:
+    """The router over all ``n_experts``; the matrices of ``n_held`` of
+    them (all where 0); with ``router_bias`` the router's correction
+    bias (one float32 per expert, used in choosing, never trained)."""
     keys = jax.random.split(key, 4)
     s = d_model ** -0.5
+    held = n_held or n_experts
     p = {
         "router": (jax.random.normal(keys[0], (d_model, n_experts)) * s
                    ).astype(jnp.float32),
         # routed experts, stacked: (E, d_model, d_expert)…
         "we_up": (jax.random.normal(keys[1],
-                  (n_experts, d_model, d_expert)) * s).astype(dtype),
+                  (held, d_model, d_expert)) * s).astype(dtype),
         "we_down": (jax.random.normal(keys[2],
-                    (n_experts, d_expert, d_model))
+                    (held, d_expert, d_model))
                     * d_expert ** -0.5).astype(dtype),
     }
     if act == "swiglu":
         p["we_gate"] = (jax.random.normal(keys[3],
-                        (n_experts, d_model, d_expert)) * s).astype(dtype)
+                        (held, d_model, d_expert)) * s).astype(dtype)
+    if router_bias:
+        p["bias"] = jnp.zeros((n_experts,), jnp.float32)
     if n_shared:
         p["shared"] = init_mlp(jax.random.fold_in(key, 7), d_model,
                                d_expert * n_shared, act, dtype)
@@ -458,6 +534,138 @@ def moe_block(p, x, *, n_experts: int, top_k: int, act: str = "swiglu",
         y = y + jax.vmap(lambda xg: mlp_block(p["shared"], xg, act))(xt)
     aux = moe_aux_loss(probs, gate_idx, n_experts)
     return y.reshape(b, s, d), aux
+
+
+def gmm_fused(m: int, k: int, n: int) -> bool:
+    """Whether ``grouped_matmul`` runs its TPU kernels for ``m`` rows of
+    ``k`` times ``k`` x ``n``: on one TPU (the compiler partitions no
+    Pallas kernel), at shapes the kernels tile."""
+    return (jax.default_backend() == "tpu" and _devices_spanned() == 1
+            and gmm_tiling(m, k, n) is not None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sort_rows(xt, order, inv, k: int):
+    """xt's row ``order // k`` for each entry of ``order``: each token's
+    row once for each of its k choices, in the order of ``order``.  Its
+    backward gathers by ``inv`` (``order``'s inverse) and sums each
+    token's k rows, where autodiff of the gather would scatter-add."""
+    return xt[order // k]
+
+
+def _sort_rows_fwd(xt, order, inv, k):
+    return xt[order // k], (order, inv)
+
+
+def _sort_rows_bwd(k, res, ct):
+    _, inv = res
+    d = ct.shape[-1]
+    dx = ct[inv].reshape(-1, k, d).astype(jnp.float32).sum(1)
+    return dx.astype(ct.dtype), None, None
+
+
+_sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, idx, inv):
+    """x[idx] for a permutation ``idx``; its backward gathers by ``inv``,
+    the inverse, where autodiff of the gather would scatter."""
+    return x[idx]
+
+
+def _permute_rows_fwd(x, idx, inv):
+    return x[idx], (idx, inv)
+
+
+def _permute_rows_bwd(res, ct):
+    _, inv = res
+    return ct[inv], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def route(p, xt, *, top_k: int, router: str = "softmax",
+          routed_scaling: float = 1.0):
+    """The router of a dropless MoE layer, in float32 over every expert.
+    ``softmax``: top-k of softmax(x·W_r).  ``sigmoid`` (DeepSeek-V3's
+    ``noaux_tc``): s = sigmoid(x·W_r), the experts chosen by top-k of
+    s + b (``bias``, which takes no gradient), weighted by s.  The k
+    weights are normalised to sum 1, then multiplied by
+    ``routed_scaling``.  xt (T, D) -> (probs (T, E),
+    weights (T, K), experts (T, K))."""
+    logits = jnp.matmul(xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if router == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        bias = jax.lax.stop_gradient(p["bias"])
+        _, idx = jax.lax.top_k(probs + bias, top_k)
+        weights = jnp.take_along_axis(probs, idx, -1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = jax.lax.top_k(probs, top_k)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return probs, weights * routed_scaling, idx
+
+
+def moe_block_held(p, x, *, n_experts: int, top_k: int, n_held: int = 0,
+                   held_offset: int = 0, act: str = "swiglu",
+                   router: str = "softmax", routed_scaling: float = 1.0):
+    """Dropless MoE over the experts this device holds: ``n_held``
+    experts from ``held_offset`` (all where ``n_held`` is 0), routed over
+    all ``n_experts``.  Every (token, k) pair is sorted by expert; the
+    held experts' rows go through ``grouped_matmul`` and come back
+    weighted; pairs routed to experts held elsewhere add nothing here,
+    as on one chip of an expert-parallel layer before its exchange.  No
+    row is dropped and no capacity is set.  The shared experts, which
+    every device computes, are added once.  Router, top-k, sort, unsort
+    and combine run under the scope ``moe_route``.
+
+    x (B, S, D) -> (y, stats): ``aux`` the Switch load-balancing loss
+    for a softmax router (0 for sigmoid, which has none), and over the
+    held experts ``moe_rows_held`` (the rows computed) and
+    ``moe_load_max_over_mean`` (the busiest expert's rows over the
+    mean)."""
+    b, s, d = x.shape
+    t, kk = b * s, top_k
+    held = n_held or n_experts
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe_route"):
+        probs, weights, idx = route(p, xt, top_k=kk, router=router,
+                                    routed_scaling=routed_scaling)
+        flat = idx.reshape(t * kk)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        x_sorted = _sort_rows(xt, order, inv, kk)
+        sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts), axis=0,
+                        dtype=jnp.int32)
+    f = p["we_up"].shape[-1]
+    fused = gmm_fused(t * kk, d, f) and gmm_fused(t * kk, f, d)
+
+    def gmm(a, w):
+        return grouped_matmul(a, w, sizes, held_offset, fused=fused)
+    if act == "swiglu":
+        hid = jax.nn.silu(gmm(x_sorted, p["we_gate"])) * gmm(x_sorted,
+                                                            p["we_up"])
+    else:
+        hid = jax.nn.gelu(gmm(x_sorted, p["we_up"]))
+    y_sorted = gmm(hid, p["we_down"])
+    with jax.named_scope("moe_route"):
+        mine = (idx >= held_offset) & (idx < held_offset + held)
+        unsorted = _permute_rows(y_sorted, inv, order).reshape(t, kk, d)
+        y = jnp.sum(jnp.where(mine, weights, 0.0)[..., None]
+                    * unsorted.astype(jnp.float32), axis=1).astype(x.dtype)
+    if "shared" in p:
+        y = y + mlp_block(p["shared"], xt, act)
+    counts = sizes[held_offset:held_offset + held].astype(jnp.float32)
+    stats = {"aux": (jnp.zeros((), jnp.float32) if router == "sigmoid"
+                     else moe_aux_loss(probs, idx, n_experts)),
+             "moe_rows_held": jnp.sum(counts),
+             "moe_load_max_over_mean": jnp.max(counts)
+             / jnp.maximum(jnp.mean(counts), 1e-9)}
+    return y.reshape(b, s, d), stats
 
 
 def moe_block_ep(p, x, *, n_experts: int, top_k: int, act: str = "swiglu",

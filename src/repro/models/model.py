@@ -6,15 +6,22 @@ Layers are stacked (leading ``n_layers`` axis) and applied under
 whole stack shards under pjit.  Training remat is per-layer
 (``jax.checkpoint`` around the scan body, policy configurable).
 
-A decoder layer's attention and dense MLP, each with its pre-norm, run
-under ``jax.named_scope("attn")`` and ``("mlp")``, and the LM head with
-the cross-entropy under ``("lm_head_ce")``: the names reach the
-compiled ops' metadata, where a device trace finds them (forward, remat
-recompute and backward alike).
+A decoder layer's attention and its MLP or expert block, each with its
+pre-norm, run under ``jax.named_scope("attn")`` and ``("mlp")``; an
+expert block nests ``("moe")`` inside ``mlp``, and its routing
+``("moe_route")`` inside that.  The LM head with the cross-entropy runs
+under ``("lm_head_ce")``.  The names reach the compiled ops' metadata,
+where a device trace finds them (forward, remat recompute and backward
+alike).
+
+An MoE stack may lead with ``n_dense_layers`` dense layers (width
+``d_ff``): they stack apart, in ``params["dense_layers"]``, and run
+before ``params["layers"]``.
 
 Public entry points (all pure):
   init(cfg, key)                         -> params
   train_loss(cfg, params, batch)         -> scalar loss
+  train_loss_and_stats(cfg, params, batch) -> (loss, expert-load stats)
   prefill(cfg, params, tokens, …)        -> (logits, cache)
   decode_step(cfg, params, token, cache) -> (logits, cache)
   init_cache(cfg, batch, max_seq)        -> cache pytree
@@ -38,6 +45,15 @@ class MoECfg:
     n_shared: int = 0
     d_expert: int = 0          # per-expert FFN width
     capacity_factor: float = 1.25
+    # router: "softmax", or "sigmoid" (DeepSeek-V3 noaux_tc: top-k of
+    # sigmoid scores plus a correction bias, weighted by the scores); the
+    # k weights are normalised, then scaled by routed_scaling
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    # the experts this device holds, n_held from held_offset (0 = all):
+    # one chip's share of an expert-parallel layer
+    n_held: int = 0
+    held_offset: int = 0
 
 
 @dataclass(frozen=True)
@@ -60,6 +76,16 @@ class ArchConfig:
     d_ff: int
     vocab: int
     head_dim: int = 0          # 0 -> d_model // n_heads
+    rms_norm_eps: float = 1e-6
+    # multi-head latent attention (DeepSeek-V2/V3): a latent KV rank, 0 =
+    # none; query/key heads of nope + rope dims, values of v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading dense layers (width d_ff) of an MoE stack; n_layers counts
+    # them
+    n_dense_layers: int = 0
     qkv_bias: bool = False
     rope: bool = True
     rope_theta: float = 1e4
@@ -96,6 +122,8 @@ class ArchConfig:
     source: str = ""
 
     def __post_init__(self):
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoECfg(**self.moe))
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim",
                                self.d_model // max(self.n_heads, 1))
@@ -120,10 +148,16 @@ class ArchConfig:
                         else max(1, min(self.n_kv_heads, n_heads))),
             dtype=dtype, remat="none")
         if self.moe:
-            kw["moe"] = MoECfg(n_experts=4,
-                               top_k=min(2, self.moe.top_k),
-                               n_shared=min(1, self.moe.n_shared),
-                               d_expert=d_ff // 2)
+            kw["moe"] = dataclasses.replace(
+                self.moe, n_experts=4, top_k=min(2, self.moe.top_k),
+                n_shared=min(1, self.moe.n_shared), d_expert=d_ff // 2,
+                n_held=min(2, self.moe.n_held), held_offset=0)
+        if self.kv_lora_rank:
+            hd = d_model // n_heads
+            kw.update(kv_lora_rank=d_model // 4, qk_nope_head_dim=hd,
+                      qk_rope_head_dim=hd // 2, v_head_dim=hd)
+        if self.n_dense_layers:
+            kw["n_dense_layers"] = 1
         if self.ssm:
             kw["ssm"] = SSMCfg(state=8, version=self.ssm.version,
                                headdim=16)
@@ -150,6 +184,11 @@ def params_count(cfg: ArchConfig, active_only: bool = False) -> int:
     attn = d * hq * hd + 2 * d * hkv * hd + hq * hd * d
     if cfg.qkv_bias:
         attn += (hq + 2 * hkv) * hd
+    if cfg.kv_lora_rank:
+        r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+        attn = (d * hq * (nope + rope) + d * (r + rope) + r
+                + r * hq * (nope + dv) + hq * dv * d)
     n_mlp_mats = 3 if cfg.act == "swiglu" else 2
     n = 0
     if cfg.ssm:
@@ -174,13 +213,17 @@ def params_count(cfg: ArchConfig, active_only: bool = False) -> int:
         if cfg.moe:
             e = cfg.moe
             per_expert = n_mlp_mats * d * e.d_expert
-            moe_all = e.n_experts * per_expert + d * e.n_experts
-            moe_act = e.top_k * per_expert + d * e.n_experts
+            router = d * e.n_experts + (e.n_experts if e.router == "sigmoid"
+                                        else 0)
+            moe_all = (e.n_held or e.n_experts) * per_expert + router
+            moe_act = e.top_k * per_expert + router
             if e.n_shared:
                 shared = n_mlp_mats * d * e.d_expert * e.n_shared
                 moe_all += shared
                 moe_act += shared
+            dense = per_layer + n_mlp_mats * d * dff
             per_layer += moe_act if active_only else moe_all
+            n += cfg.n_dense_layers * (dense - per_layer)
         else:
             per_layer += n_mlp_mats * d * dff
         n += cfg.n_layers * per_layer
@@ -240,24 +283,37 @@ def init(cfg: ArchConfig, key: jax.Array) -> dict:
             "mlp": L.init_mlp(keys[4], cfg.d_model, cfg.d_ff, cfg.act, dt),
         }
     else:                                      # attention stacks
-        def one(k):
+        def one(k, dense):
             k1, k2 = jax.random.split(k)
+            if cfg.kv_lora_rank:
+                attn = L.init_mla(k1, cfg.d_model, cfg.n_heads,
+                                  cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                                  cfg.qk_rope_head_dim, cfg.v_head_dim, dt)
+            else:
+                attn = L.init_attn(k1, cfg.d_model, cfg.n_heads,
+                                   cfg.n_kv_heads, cfg.head_dim,
+                                   cfg.qkv_bias, dt)
             lp = {
                 "norm1": jnp.ones((cfg.d_model,), dt),
-                "attn": L.init_attn(k1, cfg.d_model, cfg.n_heads,
-                                    cfg.n_kv_heads, cfg.head_dim,
-                                    cfg.qkv_bias, dt),
+                "attn": attn,
                 "norm2": jnp.ones((cfg.d_model,), dt),
             }
-            if cfg.moe:
+            if cfg.moe and not dense:
                 lp["moe"] = L.init_moe(k2, cfg.d_model, cfg.moe.d_expert,
                                        cfg.moe.n_experts,
-                                       cfg.moe.n_shared, cfg.act, dt)
+                                       cfg.moe.n_shared, cfg.act, dt,
+                                       n_held=cfg.moe.n_held,
+                                       router_bias=cfg.moe.router
+                                       == "sigmoid")
             else:
                 lp["mlp"] = L.init_mlp(k2, cfg.d_model, cfg.d_ff,
                                        cfg.act, dt)
             return lp
-        p["layers"] = _stack(lambda k: one(k), keys[2], cfg.n_layers)
+        p["layers"] = _stack(lambda k: one(k, False), keys[2],
+                             cfg.n_layers - cfg.n_dense_layers)
+        if cfg.n_dense_layers:
+            p["dense_layers"] = _stack(lambda k: one(k, True), keys[7],
+                                       cfg.n_dense_layers)
         if cfg.n_enc_layers:                   # whisper enc-dec
             def enc_one(k):
                 k1, k2 = jax.random.split(k)
@@ -288,7 +344,7 @@ def init(cfg: ArchConfig, key: jax.Array) -> dict:
 # ---------------------------------------------------------------------------
 
 def _norm(cfg, w, x):
-    return L.rmsnorm(x, w)
+    return L.rmsnorm(x, w, cfg.rms_norm_eps)
 
 
 def _dec_layer(cfg, lp, x, enc_out=None, cross_lp=None,
@@ -300,7 +356,7 @@ def _dec_layer(cfg, lp, x, enc_out=None, cross_lp=None,
                                 headdim=cfg.ssm.headdim,
                                 unroll_chunks=cfg.unroll_scans,
                                 chunk=cfg.ssm_chunk)
-        return x + h, jnp.zeros((), jnp.float32)
+        return x + h, {"aux": jnp.zeros((), jnp.float32)}
     with jax.named_scope("attn"):
         a, _ = L.attention_block(lp["attn"], _norm(cfg, lp["norm1"], x),
                                  cfg, mrope_positions=mrope_positions,
@@ -311,19 +367,36 @@ def _dec_layer(cfg, lp, x, enc_out=None, cross_lp=None,
         c = _cross_attn(cfg, cross_lp["attn"],
                         _norm(cfg, cross_lp["norm"], x), enc_out)
         x = x + c
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.moe:
-        m, aux = _moe_dispatch(cfg, lp["moe"], _norm(cfg, lp["norm2"], x))
-    else:
-        with jax.named_scope("mlp"):
+    stats = {"aux": jnp.zeros((), jnp.float32)}
+    with jax.named_scope("mlp"):
+        if "moe" in lp:
+            with jax.named_scope("moe"):
+                m, stats = _moe_dispatch(cfg, lp["moe"],
+                                         _norm(cfg, lp["norm2"], x))
+        else:
             m = L.mlp_block(lp["mlp"], _norm(cfg, lp["norm2"], x), cfg.act)
-    return x + m, aux
+    return x + m, stats
 
 
 def _moe_dispatch(cfg, moe_params, h):
     """Choose the MoE implementation: explicit shard_map all-to-all EP
-    when the launch layer requested it and the shapes divide, else the
-    pjit-auto grouped dispatch."""
+    when the launch layer requested it and the shapes divide; the
+    dropless layer over the held experts (``moe_block_held``) where the
+    config holds a share of them or the step runs on one device; else
+    the pjit-auto grouped dispatch, which drops rows past its capacity.
+    Returns (y, stats): ``aux`` the load-balancing loss, and the held
+    layer's counters."""
+    e = cfg.moe
+    if e.n_held or L._devices_spanned() == 1:
+        return L.moe_block_held(
+            moe_params, h, n_experts=e.n_experts, top_k=e.top_k,
+            n_held=e.n_held, held_offset=e.held_offset, act=cfg.act,
+            router=e.router, routed_scaling=e.routed_scaling)
+    y, aux = _moe_dispatch_capacity(cfg, moe_params, h)
+    return y, {"aux": aux}
+
+
+def _moe_dispatch_capacity(cfg, moe_params, h):
     kw = dict(n_experts=cfg.moe.n_experts, top_k=cfg.moe.top_k,
               act=cfg.act, capacity_factor=cfg.moe.capacity_factor)
     amap = L._AXIS_MAP
@@ -357,9 +430,11 @@ def _cross_attn(cfg, ap, x, enc_out):
 
 def _run_decoder(cfg: ArchConfig, p: dict, x: jax.Array,
                  enc_out=None, mrope_positions=None):
-    """x: (B, S, D) embedded inputs -> (hidden, aux_loss)."""
+    """x: (B, S, D) embedded inputs -> (hidden, aux_loss, stats): the
+    held expert layers' counters (``moe_rows_held`` their mean over the
+    layers, ``moe_load_max_over_mean`` the largest), else {}."""
     if cfg.hybrid_every:
-        return _run_hybrid(cfg, p, x)
+        return _run_hybrid(cfg, p, x) + ({},)
 
     have_cross = "cross_layers" in p
 
@@ -369,18 +444,27 @@ def _run_decoder(cfg: ArchConfig, p: dict, x: jax.Array,
             lp, cross_lp = lp
         else:
             cross_lp = None
-        x, aux = _dec_layer(cfg, lp, x, enc_out=enc_out,
-                            cross_lp=cross_lp,
-                            mrope_positions=mrope_positions)
+        x, stats = _dec_layer(cfg, lp, x, enc_out=enc_out,
+                              cross_lp=cross_lp,
+                              mrope_positions=mrope_positions)
         x = L.constrain(x, "dp", "sp", None)
-        return x, aux
+        return x, stats
 
     fn = body
     if cfg.remat == "full":
         fn = jax.checkpoint(body)
+    dense_aux = None
+    if "dense_layers" in p:
+        x, dense = jax.lax.scan(fn, x, p["dense_layers"],
+                                unroll=cfg.unroll_scans)
+        dense_aux = jnp.sum(dense["aux"])
     xs = (p["layers"], p["cross_layers"]) if have_cross else p["layers"]
-    x, auxs = jax.lax.scan(fn, x, xs, unroll=cfg.unroll_scans)
-    return x, jnp.sum(auxs)
+    x, stats = jax.lax.scan(fn, x, xs, unroll=cfg.unroll_scans)
+    aux = jnp.sum(stats.pop("aux"))
+    if dense_aux is not None:
+        aux = aux + dense_aux
+    reduce = {"moe_rows_held": jnp.mean, "moe_load_max_over_mean": jnp.max}
+    return x, aux, {k: reduce[k](v) for k, v in stats.items()}
 
 
 def _run_hybrid(cfg: ArchConfig, p: dict, x: jax.Array):
@@ -444,15 +528,21 @@ def _logits(cfg: ArchConfig, p: dict, h: jax.Array) -> jax.Array:
 def train_loss(cfg: ArchConfig, p: dict, batch: dict) -> jax.Array:
     """batch: tokens (B,S) int32, labels (B,S) int32 (-1 = ignore);
     audio adds frames (B,enc_seq,D); vlm may add mrope_positions."""
+    return train_loss_and_stats(cfg, p, batch)[0]
+
+
+def train_loss_and_stats(cfg: ArchConfig, p: dict, batch: dict):
+    """``train_loss`` and the held expert layers' counters of the step
+    (``_run_decoder``; {} for a model without them)."""
     x = L.constrain(p["embed"][batch["tokens"]], "dp", "sp", None)
     enc_out = None
     if cfg.n_enc_layers:
         enc_out = _run_encoder(cfg, p, batch["frames"].astype(cfg.jdtype))
-    h, aux = _run_decoder(cfg, p, x, enc_out=enc_out,
-                          mrope_positions=batch.get("mrope_positions"))
+    h, aux, stats = _run_decoder(cfg, p, x, enc_out=enc_out,
+                                 mrope_positions=batch.get("mrope_positions"))
     with jax.named_scope("lm_head_ce"):
         loss = _ce_loss(cfg, p, h, batch["labels"])
-    return loss + 0.01 * aux
+    return loss + 0.01 * aux, stats
 
 
 def _ce_token_stats(cfg, p, h, labels):
@@ -518,6 +608,13 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int) -> dict:
                                  jnp.float32)
         cache["k"] = jnp.zeros((n_groups, batch, hkv, win, hd), dt)
         cache["v"] = jnp.zeros((n_groups, batch, hkv, win, hd), dt)
+    elif cfg.kv_lora_rank:
+        # the expanded per-head keys and values, not the latent
+        cache["k"] = jnp.zeros((cfg.n_layers, batch, cfg.n_heads, max_seq,
+                                cfg.qk_nope_head_dim + cfg.qk_rope_head_dim),
+                               dt)
+        cache["v"] = jnp.zeros((cfg.n_layers, batch, cfg.n_heads, max_seq,
+                                cfg.v_head_dim), dt)
     else:
         cache["k"] = jnp.zeros((cfg.n_layers, batch, hkv, max_seq, hd), dt)
         cache["v"] = jnp.zeros((cfg.n_layers, batch, hkv, max_seq, hd), dt)
@@ -544,8 +641,8 @@ def prefill(cfg: ArchConfig, p: dict, batch: dict, max_seq: int):
     # for shapes/roofline purposes prefill = decoder forward; cache fill
     # is a cheap scatter of the per-layer K/V (done inside attention on
     # the serving path; here we run the stack and return hidden states)
-    h, _ = _run_decoder(cfg, p, x, enc_out=enc_out,
-                        mrope_positions=batch.get("mrope_positions"))
+    h, _, _ = _run_decoder(cfg, p, x, enc_out=enc_out,
+                           mrope_positions=batch.get("mrope_positions"))
     logits = _logits(cfg, p, h[:, -1:, :])
     cache["len"] = jnp.full((), s, jnp.int32)
     return logits, cache
@@ -626,11 +723,8 @@ def decode_step(cfg: ArchConfig, p: dict, token: jax.Array, cache: dict):
             if have_cross:
                 x = x + _cross_cached(cfg, clp, x, ck, cv)
             h = _norm(cfg, lp["norm2"], x)
-            if cfg.moe:
-                m, _ = L.moe_block(lp["moe"], h,
-                                   n_experts=cfg.moe.n_experts,
-                                   top_k=cfg.moe.top_k, act=cfg.act,
-                                   capacity_factor=cfg.moe.capacity_factor)
+            if "moe" in lp:
+                m, _ = _moe_dispatch(cfg, lp["moe"], h)
                 x = x + m
             else:
                 x = x + L.mlp_block(lp["mlp"], h, cfg.act)
@@ -645,9 +739,18 @@ def decode_step(cfg: ArchConfig, p: dict, token: jax.Array, cache: dict):
                                                unroll=cfg.unroll_scans)
             cache = dict(cache, k=kc, v=vc, len=cache["len"] + 1)
         else:
-            x, (kc, vc) = jax.lax.scan(
-                body, x, (p["layers"], cache["k"], cache["v"]),
-                unroll=cfg.unroll_scans)
+            # leading dense layers hold the first entries of the cache
+            nd = cfg.n_dense_layers if "dense_layers" in p else 0
+            kv = []
+            for lps, lo, hi in ((p.get("dense_layers"), 0, nd),
+                                (p["layers"], nd, cfg.n_layers)):
+                if hi > lo:
+                    x, kv_part = jax.lax.scan(
+                        body, x, (lps, cache["k"][lo:hi], cache["v"][lo:hi]),
+                        unroll=cfg.unroll_scans)
+                    kv.append(kv_part)
+            kc, vc = (jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+                      for parts in zip(*kv))
             cache = dict(cache, k=kc, v=vc, len=cache["len"] + 1)
 
     return _logits(cfg, p, x), cache

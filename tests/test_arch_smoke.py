@@ -91,7 +91,8 @@ class TestParamCount:
         expect = {"qwen2.5-32b": 32e9, "dbrx-132b": 132e9,
                   "falcon-mamba-7b": 7e9, "minicpm-2b": 2.7e9,
                   "deepseek-moe-16b": 16e9, "granite-20b": 20e9,
-                  "zamba2-2.7b": 2.7e9, "qwen2-vl-7b": 7e9}
+                  "zamba2-2.7b": 2.7e9, "qwen2-vl-7b": 7e9,
+                  "moonlight-16b-a3b": 16e9}
         for name, target in expect.items():
             n = params_count(get_config(name))
             assert 0.5 * target < n < 1.8 * target, \

@@ -213,3 +213,56 @@ def test_fused_refuses_a_window():
     q = jax.ShapeDtypeStruct((1, 2, 256, 64), F32)
     with pytest.raises(ValueError, match="window"):
         jax.eval_shape(lambda q: train_attention(q, q, q, window=64), q)
+
+
+def _mla_cfg():
+    return get_config("moonlight-16b-a3b").reduced(
+        n_layers=2, d_model=128, d_ff=256, vocab=256, n_heads=2)
+
+
+def _mla_block_paths(**kw) -> dict:
+    cfg = _mla_cfg()
+    p = jax.eval_shape(lambda: L.init_mla(
+        jax.random.PRNGKey(0), cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, F32))
+    x = jax.ShapeDtypeStruct((2, 256, cfg.d_model), F32)
+    return _paths(lambda p, x: L.attention_block(p, x, cfg, **kw)[0], p, x)
+
+
+@pytest.mark.parametrize("tpu,path", [(True, "mla_fused"),
+                                      (False, "mla_platform")])
+def test_latent_attention_is_tallied_apart(monkeypatch, tpu, path):
+    if tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "device_count", lambda: 1)
+    assert _mla_block_paths() == {path: 1}
+
+
+def test_latent_attention_matches_naive(monkeypatch):
+    # the splash kernels, interpreted, at d_qk 48 and d_v 32 against
+    # naive attention scaled by d_qk^-1/2; q pre-scaled, no padding
+    from jax.experimental.pallas.ops.tpu.splash_attention import \
+        splash_attention_kernel as splash
+    from jax.experimental.pallas.ops.tpu.splash_attention import \
+        splash_attention_mask as splash_mask
+
+    from repro.kernels import train_attention as ta
+
+    def interpreted(heads, seq, block):
+        mask = splash_mask.MultiHeadMask(
+            [splash_mask.CausalMask((seq, seq)) for _ in range(heads)])
+        sizes = splash.BlockSizes(
+            block_q=block, block_kv=block, block_kv_compute=block,
+            block_q_dkv=block, block_kv_dkv=block,
+            block_kv_dkv_compute=block, block_q_dq=block, block_kv_dq=block)
+        return splash.make_splash_mha_single_device(
+            mask, block_sizes=sizes, interpret=True)
+    monkeypatch.setattr(ta, "_splash_kernel", interpreted)
+    q, k = _rand(0, (1, 2, 256, 48)), _rand(1, (1, 2, 256, 48))
+    v, do = _rand(2, (1, 2, 256, 32)), _rand(3, (1, 2, 256, 32))
+    got, vjp = jax.vjp(ta.latent_attention, q, k, v)
+    got_grads = vjp(do)
+    want, vjp = jax.vjp(lambda q, k, v: naive_attention(q, k, v), q, k, v)
+    want_grads = vjp(do)
+    for a, b in zip((got, *got_grads), (want, *want_grads)):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4)

@@ -126,6 +126,45 @@ def test_train_step_over_four_chips(v5e_2x2, monkeypatch, mesh_seen_by):
     assert "tpu_custom_call" not in lowered.compile().as_text()
 
 
+def test_moonlight_cell_step_fits_one_chip(one_chip, monkeypatch):
+    # the benchmark cell's training step at its cut widths (Moonlight-
+    # 16B-A3B, 6 of 27 layers, 8 of 64 experts held, 20480 vocabulary
+    # rows, 2 x 8192 tokens): latent attention on the splash kernels,
+    # the experts on the megablox kernels, within the chip's 16 GB
+    import dataclasses
+    from repro.launch.train import build_step
+    from repro.optim import adamw_init, cosine_schedule
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    base = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(base, n_layers=6, vocab=20480,
+                              moe=dataclasses.replace(base.moe, n_held=8))
+
+    def place(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    state = jax.tree.map(place, {
+        "params": params, "opt": jax.eval_shape(adamw_init, params),
+        "step": jax.ShapeDtypeStruct((), jnp.int32)})
+    batch = {k: place(jax.ShapeDtypeStruct((2, 8192), jnp.int32))
+             for k in ("tokens", "labels")}
+    before = collections.Counter(L.attention_paths())
+    compiled = build_step(cfg, cosine_schedule(3e-4, 100)).lower(
+        state, batch).compile()
+    assert set(collections.Counter(L.attention_paths()) - before) == {
+        "mla_fused"}
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < 14.9 * 2**30, used / 2**30
+    kernels = collections.Counter(
+        line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
+        for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert {"splash_mha_fwd_residuals", "splash_mha_dkv_no_residuals",
+            "splash_mha_dq_no_residuals", "gmm", "tgmm"} <= set(kernels)
+
+
 def test_rmsnorm_rows_not_a_multiple_of_8(one_chip):
     _compile(lambda x, w: rmsnorm_pallas(x, w, interpret=False),
              one_chip, ((4, 1001, 1024), BF16), ((1024,), BF16))

@@ -91,6 +91,8 @@ def compiled_step_hlo(family: str) -> str:
 
 
 FAMILIES = ("qwen1.5-0.5b", "minicpm-2b")
+# latent attention, a leading dense layer and an expert layer
+MOE = "moonlight-16b-a3b"
 
 
 def test_families_cover_bias_and_tied_head():
@@ -99,7 +101,7 @@ def test_families_cover_bias_and_tied_head():
     assert not minicpm.qkv_bias
 
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", FAMILIES + (MOE,))
 def test_every_matmul_lies_under_one_layer_scope(family):
     hlo = compiled_step_hlo(family)
     seen = {s: 0 for s in LAYER_SCOPES}
@@ -123,6 +125,26 @@ def test_every_matmul_lies_under_one_layer_scope(family):
                        and "while" in comps[comps.index("attn"):])
     assert all(seen.values()), seen
     assert remat and backward and custom_bwd
+
+
+def test_expert_layer_nests_moe_and_its_routing_in_mlp():
+    # an expert layer's block lies under ``mlp`` as a dense MLP does, in
+    # ``moe``; its router, top-k, sorts and the gather and scatter of
+    # rows in ``moe_route`` inside that
+    hlo = compiled_step_hlo(MOE)
+    opcodes = {"moe_route": set(), "moe": set()}
+    for line in hlo.splitlines():
+        m, on = _INSTR.match(line), _OP_NAME.search(line)
+        if not (m and on):
+            continue
+        comps = components(on.group(1))
+        for scope in ("moe_route", "moe"):
+            if scope in comps:
+                assert "mlp" in comps[:comps.index(scope)], on.group(1)
+                opcodes[scope].add(m.group(1))
+        if m.group(1) == "sort":
+            assert "moe_route" in comps, on.group(1)
+    assert "sort" in opcodes["moe_route"] and "dot" in opcodes["moe"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
