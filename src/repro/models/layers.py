@@ -544,46 +544,82 @@ def gmm_fused(m: int, k: int, n: int) -> bool:
             and gmm_tiling(m, k, n) is not None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sort_rows(xt, order, inv, k: int):
-    """xt's row ``order // k`` for each entry of ``order``: each token's
-    row once for each of its k choices, in the order of ``order``.  Its
-    backward gathers by ``inv`` (``order``'s inverse) and sums each
-    token's k rows, where autodiff of the gather would scatter-add."""
-    return xt[order // k]
+def row_ladder(t: int, k: int, n_held: int, n_experts: int) -> tuple:
+    """The static sizes the held expert layer's row buffer may take, for
+    ``t`` tokens choosing ``k`` of ``n_experts`` of which ``n_held`` are
+    held: doubling from twice the expected rows (t·k·n_held/n_experts,
+    rounded up to a multiple of 512) up to the worst case
+    t·min(k, n_held), which is always the last."""
+    top = t * min(k, n_held)
+    expected = -(-t * k * n_held // n_experts)
+    rung = 2 * (-(-expected // 512) * 512)
+    rungs = []
+    while rung < top:
+        rungs.append(rung)
+        rung *= 2
+    return tuple(rungs) + (top,)
 
 
-def _sort_rows_fwd(xt, order, inv, k):
-    return xt[order // k], (order, inv)
+def _to_tokens(rows, src, valid, t: int, k: int):
+    """Each token's sum of its rows in the buffer, in float32: ``rows``
+    (R, D) added to their tokens.  ``src`` (R,) is each row's (token,
+    k) pair, T·K-flat; rows where ``valid`` (R,) is false hold no pair
+    and add nothing."""
+    r = jnp.where(valid[:, None], rows.astype(jnp.float32), 0.0)
+    return jnp.zeros((t, rows.shape[-1]), jnp.float32).at[src // k].add(r)
 
 
-def _sort_rows_bwd(k, res, ct):
-    _, inv = res
-    d = ct.shape[-1]
-    dx = ct[inv].reshape(-1, k, d).astype(jnp.float32).sum(1)
-    return dx.astype(ct.dtype), None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _take_rows(xt, src, valid, t: int, k: int):
+    """The buffer's rows: xt's (T, D) token row for each of the R pairs
+    ``src`` (T·K-flat, sorted by expert).  Its backward adds the valid
+    rows back to their tokens (``_to_tokens``)."""
+    return xt[src // k]
 
 
-_sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
+def _take_rows_fwd(xt, src, valid, t, k):
+    return xt[src // k], (src, valid)
+
+
+def _take_rows_bwd(t, k, res, ct):
+    src, valid = res
+    return _to_tokens(ct, src, valid, t, k).astype(ct.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 @jax.custom_vjp
-def _permute_rows(x, idx, inv):
-    """x[idx] for a permutation ``idx``; its backward gathers by ``inv``,
-    the inverse, where autodiff of the gather would scatter."""
-    return x[idx]
+def _combine_rows(rows, weights, src, valid):
+    """Each token's weighted sum of its rows in the buffer: rows (R, D)
+    the experts' outputs, weights (T, K) float32, ``src`` (R,) each
+    row's pair, ``valid`` (R,) whether a row holds a pair.  Returns
+    (T, D) in the rows' dtype, summed in float32.  Its backward gathers
+    the R rows' cotangents in the rows' dtype, where autodiff would
+    gather them in float32."""
+    t, k = weights.shape
+    w = weights.reshape(-1)[src]
+    return _to_tokens(rows * w[:, None], src, valid, t, k).astype(rows.dtype)
 
 
-def _permute_rows_fwd(x, idx, inv):
-    return x[idx], (idx, inv)
+def _combine_rows_fwd(rows, weights, src, valid):
+    return (_combine_rows(rows, weights, src, valid),
+            (rows, weights, src, valid))
 
 
-def _permute_rows_bwd(res, ct):
-    _, inv = res
-    return ct[inv], None, None
+def _combine_rows_bwd(res, ct):
+    rows, weights, src, valid = res
+    t, k = weights.shape
+    ct_rows = ct[src // k].astype(jnp.float32)
+    w = jnp.where(valid, weights.reshape(-1)[src], 0.0)
+    d_rows = (ct_rows * w[:, None]).astype(rows.dtype)
+    d_w = jnp.where(valid, jnp.sum(ct_rows * rows.astype(jnp.float32), -1),
+                    0.0)
+    d_weights = jnp.zeros(t * k, jnp.float32).at[src].add(d_w)
+    return d_rows, d_weights.reshape(t, k), None, None
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 def route(p, xt, *, top_k: int, router: str = "softmax",
@@ -616,18 +652,22 @@ def moe_block_held(p, x, *, n_experts: int, top_k: int, n_held: int = 0,
     """Dropless MoE over the experts this device holds: ``n_held``
     experts from ``held_offset`` (all where ``n_held`` is 0), routed over
     all ``n_experts``.  Every (token, k) pair is sorted by expert; the
-    held experts' rows go through ``grouped_matmul`` and come back
+    held experts' pairs, one run of that order, are gathered into a
+    buffer of R rows, go through ``grouped_matmul`` and come back
     weighted; pairs routed to experts held elsewhere add nothing here,
-    as on one chip of an expert-parallel layer before its exchange.  No
-    row is dropped and no capacity is set.  The shared experts, which
-    every device computes, are added once.  Router, top-k, sort, unsort
-    and combine run under the scope ``moe_route``.
+    as on one chip of an expert-parallel layer before its exchange.  R
+    is the smallest size of ``row_ladder`` that holds the step's count
+    of held pairs, chosen on the device (``lax.switch``), so no row is
+    dropped and no capacity is set; the rows past the count belong to no
+    held expert and reach nothing.  The shared experts, which every
+    device computes, are added once.  Router, top-k, sort, the gather
+    and the combine run under the scope ``moe_route``.
 
     x (B, S, D) -> (y, stats): ``aux`` the Switch load-balancing loss
     for a softmax router (0 for sigmoid, which has none), and over the
-    held experts ``moe_rows_held`` (the rows computed) and
-    ``moe_load_max_over_mean`` (the busiest expert's rows over the
-    mean)."""
+    held experts ``moe_rows_held`` (the rows computed),
+    ``moe_rows_buffered`` (R) and ``moe_load_max_over_mean`` (the
+    busiest expert's rows over the mean)."""
     b, s, d = x.shape
     t, kk = b * s, top_k
     held = n_held or n_experts
@@ -637,32 +677,55 @@ def moe_block_held(p, x, *, n_experts: int, top_k: int, n_held: int = 0,
                                     routed_scaling=routed_scaling)
         flat = idx.reshape(t * kk)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inv = jnp.argsort(order).astype(jnp.int32)
-        x_sorted = _sort_rows(xt, order, inv, kk)
         sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts), axis=0,
                         dtype=jnp.int32)
+        counts = sizes[held_offset:held_offset + held]
+        before = jnp.sum(sizes[:held_offset])
+        n = jnp.sum(counts)
     f = p["we_up"].shape[-1]
-    fused = gmm_fused(t * kk, d, f) and gmm_fused(t * kk, f, d)
 
-    def gmm(a, w):
-        return grouped_matmul(a, w, sizes, held_offset, fused=fused)
-    if act == "swiglu":
-        hid = jax.nn.silu(gmm(x_sorted, p["we_gate"])) * gmm(x_sorted,
-                                                            p["we_up"])
+    def rows(r, xt, weights, order, counts, before, n, we):
+        with jax.named_scope("moe_route"):
+            src = jnp.take(order, before + jnp.arange(r), mode="clip")
+            valid = jnp.arange(r) < n
+            x_rows = _take_rows(xt, src, valid, t, kk)
+        # the rows past the count form one group that no held expert owns
+        sizes_r = jnp.concatenate([counts, (r - n)[None]])
+        fused = gmm_fused(r, d, f) and gmm_fused(r, f, d)
+
+        def gmm(a, name):
+            return grouped_matmul(a, we[name], sizes_r, 0, fused=fused)
+        if act == "swiglu":
+            hid = jax.nn.silu(gmm(x_rows, "we_gate")) * gmm(x_rows, "we_up")
+        else:
+            hid = jax.nn.gelu(gmm(x_rows, "we_up"))
+        y_rows = gmm(hid, "we_down")
+        with jax.named_scope("moe_route"):
+            return _combine_rows(y_rows, weights, src, valid)
+
+    ladder = row_ladder(t, kk, held, n_experts)
+    we = {k: p[k] for k in ("we_gate", "we_up", "we_down") if k in p}
+    args = (xt, weights, order, counts, before, n, we)
+    if len(ladder) == 1:
+        rung = jnp.zeros((), jnp.int32)
+        y = rows(ladder[0], *args)
     else:
-        hid = jax.nn.gelu(gmm(x_sorted, p["we_up"]))
-    y_sorted = gmm(hid, p["we_down"])
-    with jax.named_scope("moe_route"):
-        mine = (idx >= held_offset) & (idx < held_offset + held)
-        unsorted = _permute_rows(y_sorted, inv, order).reshape(t, kk, d)
-        y = jnp.sum(jnp.where(mine, weights, 0.0)[..., None]
-                    * unsorted.astype(jnp.float32), axis=1).astype(x.dtype)
+        # each size's branch recomputes its rows in the backward: the
+        # gradient of an un-rematerialised switch has every branch return
+        # every other branch's residuals, zero-filled, which at Moonlight's
+        # shapes nearly doubles the step's temporaries
+        branches = [jax.checkpoint(functools.partial(rows, r))
+                    for r in ladder]
+        with jax.named_scope("moe_route"):
+            rung = jnp.sum(n > jnp.asarray(ladder[:-1]), dtype=jnp.int32)
+        y = jax.lax.switch(rung, branches, *args)
     if "shared" in p:
         y = y + mlp_block(p["shared"], xt, act)
-    counts = sizes[held_offset:held_offset + held].astype(jnp.float32)
+    counts = counts.astype(jnp.float32)
     stats = {"aux": (jnp.zeros((), jnp.float32) if router == "sigmoid"
                      else moe_aux_loss(probs, idx, n_experts)),
              "moe_rows_held": jnp.sum(counts),
+             "moe_rows_buffered": jnp.asarray(ladder, jnp.float32)[rung],
              "moe_load_max_over_mean": jnp.max(counts)
              / jnp.maximum(jnp.mean(counts), 1e-9)}
     return y.reshape(b, s, d), stats

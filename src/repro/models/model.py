@@ -431,8 +431,9 @@ def _cross_attn(cfg, ap, x, enc_out):
 def _run_decoder(cfg: ArchConfig, p: dict, x: jax.Array,
                  enc_out=None, mrope_positions=None):
     """x: (B, S, D) embedded inputs -> (hidden, aux_loss, stats): the
-    held expert layers' counters (``moe_rows_held`` their mean over the
-    layers, ``moe_load_max_over_mean`` the largest), else {}."""
+    held expert layers' counters (``moe_rows_held`` and
+    ``moe_rows_buffered`` their means over the layers,
+    ``moe_load_max_over_mean`` the largest), else {}."""
     if cfg.hybrid_every:
         return _run_hybrid(cfg, p, x) + ({},)
 
@@ -463,7 +464,8 @@ def _run_decoder(cfg: ArchConfig, p: dict, x: jax.Array,
     aux = jnp.sum(stats.pop("aux"))
     if dense_aux is not None:
         aux = aux + dense_aux
-    reduce = {"moe_rows_held": jnp.mean, "moe_load_max_over_mean": jnp.max}
+    reduce = {"moe_rows_held": jnp.mean, "moe_rows_buffered": jnp.mean,
+              "moe_load_max_over_mean": jnp.max}
     return x, aux, {k: reduce[k](v) for k, v in stats.items()}
 
 

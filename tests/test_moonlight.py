@@ -134,6 +134,97 @@ def test_held_experts_match_reference_under_imbalance():
     assert float(stats["aux"]) == 0.0
 
 
+def test_row_ladder_of_the_cell():
+    # Moonlight's cell: 2 x 8192 tokens, top-6, 8 of 64 experts held;
+    # 12288 rows expected, so the buffer starts at twice that
+    assert L.row_ladder(16384, 6, 8, 64) == (24576, 49152, 98304)
+    # every expert held: the worst case is twice the expected count or
+    # less, so one size and no switch
+    assert L.row_ladder(16384, 6, 64, 64) == (16384 * 6,)
+
+
+@pytest.mark.parametrize("t,k,held,n_experts", [
+    (16384, 6, 8, 64), (16384, 6, 64, 64), (4096, 2, 1, 8),
+    (1024, 6, 4, 64), (1000, 8, 3, 160), (128, 6, 4, 16)])
+def test_row_ladder_ends_at_the_worst_case(t, k, held, n_experts):
+    ladder = L.row_ladder(t, k, held, n_experts)
+    assert ladder[-1] == t * min(k, held)
+    assert list(ladder) == sorted(set(ladder))
+    assert all(r % 512 == 0 for r in ladder[:-1])
+
+
+# (bias added to every held expert's, bias added to the first held
+# expert's, the rows each rung of the ladder may hold): 1024 tokens
+# choose 6 of 64 experts, 4 held, so the ladder is (1024, 2048, 4096)
+RUNGS = {
+    # ~384 held rows: the smallest rung
+    "smallest": (0.0, 0.0, 1024),
+    # the first held expert takes every token, the others ~96 each
+    "overflow": (0.0, 1.0, 2048),
+    # every held expert takes every token: the worst case
+    "worst": (1.0, 0.0, 4096),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RUNGS))
+def test_held_layer_rungs_match_reference(case):
+    # forward and gradient against the reference, on whichever rung the
+    # step's count of held rows lands; moe_rows_buffered is that rung
+    every, first, rung = RUNGS[case]
+    c = small_config()
+    p = _moe_params(c, 64, 4, seed=9)
+    p["bias"] = p["bias"].at[4:8].add(every).at[4].add(first)
+    x = _normal(10, (2, 512, 64), 1.0)
+    ct = _normal(11, (1024, 64), 1.0)
+    assert L.row_ladder(1024, 6, 4, 64) == (1024, 2048, 4096)
+
+    def program(x, p):
+        y, stats = _held_block(c, p, x, n_experts=64)
+        return jnp.sum(y.reshape(-1, 64) * ct), stats
+
+    def reference(x, p):
+        return jnp.sum(mla_moe_lm.moe(MM, c, x.reshape(-1, 64), p) * ct)
+
+    (got, stats), (gx, gp) = jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True)(x, p)
+    want, (wx, wp) = jax.value_and_grad(reference, argnums=(0, 1))(x, p)
+    held = int(stats["moe_rows_held"])
+    assert float(stats["moe_rows_buffered"]) == rung
+    assert held <= rung and (rung == 1024 or held > rung // 2)
+    np.testing.assert_allclose(got, want, rtol=RTOL * 10)
+    np.testing.assert_allclose(gx, wx, rtol=RTOL * 10, atol=RTOL * 10)
+    for name in ("we_gate", "we_up", "we_down"):
+        np.testing.assert_allclose(gp[name], wp[name], rtol=RTOL * 10,
+                                   atol=RTOL * 10, err_msg=name)
+
+
+def test_every_expert_held_runs_one_rung():
+    # n_held 0: all 16 experts here, one buffer of every (token, k) pair
+    c = dict(small_config(), n_routed_experts=16, first_held_expert=0)
+    p = _moe_params(c, 16, 0, seed=12)
+    x = _normal(13, (2, 64, 64), 1.0)
+    ct = _normal(14, (128, 64), 1.0)
+    assert L.row_ladder(128, 6, 16, 16) == (768,)
+
+    def program(x, p):
+        y, stats = _held_block(c, p, x, n_held=0, held_offset=0)
+        return jnp.sum(y.reshape(-1, 64) * ct), stats
+
+    def reference(x, p):
+        return jnp.sum(mla_moe_lm.moe(MM, c, x.reshape(-1, 64), p) * ct)
+
+    (got, stats), (gx, gp) = jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True)(x, p)
+    want, (wx, wp) = jax.value_and_grad(reference, argnums=(0, 1))(x, p)
+    assert float(stats["moe_rows_held"]) == 128 * 6
+    assert float(stats["moe_rows_buffered"]) == 128 * 6
+    np.testing.assert_allclose(got, want, rtol=RTOL * 10)
+    np.testing.assert_allclose(gx, wx, rtol=RTOL * 10, atol=RTOL * 10)
+    for name in ("we_gate", "we_up", "we_down"):
+        np.testing.assert_allclose(gp[name], wp[name], rtol=RTOL * 10,
+                                   atol=RTOL * 10, err_msg=name)
+
+
 def test_shares_sum_to_the_whole_layer():
     # 8 chips of an EP8 layer, 2 of 16 experts each: their routed parts,
     # with the shared experts (which every chip computes) counted once,
@@ -180,6 +271,40 @@ def test_grouped_matmul_vjp_matches_einsum(fused):
             got = jax.value_and_grad(loss(gmm), argnums=(0, 1))(x, w)
     else:
         got = jax.value_and_grad(loss(gmm), argnums=(0, 1))(x, w)
+    want = jax.value_and_grad(loss(einsum), argnums=(0, 1))(x, w)
+    for g, e in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, e, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_grouped_matmul_vjp_with_pad_rows(fused):
+    # the held layer's buffer: the rows of 2 held experts, then 64 rows
+    # past the count that no held expert owns, whose outputs are zero
+    sizes = jnp.array([24, 40, 64], jnp.int32)
+    m, k, n = 128, 128, 256
+    gid = jnp.repeat(jnp.arange(3), sizes, total_repeat_length=m)
+    x = _normal(15, (m, k), 1.0)
+    w = _normal(16, (2, k, n), k ** -0.5)
+    ct = _normal(17, (m, n), 1.0)
+    held = jax.nn.one_hot(gid, 2)         # zero rows for the pad group
+
+    def einsum(x, w):
+        return jnp.einsum("mk,gkn,mg->mn", x, w, held,
+                          precision=jax.lax.Precision.HIGHEST)
+
+    def loss(fn):
+        return lambda x, w: jnp.sum(fn(x, w) * ct)
+
+    def gmm(x, w):
+        return grouped_matmul(x, w, sizes, 0, fused=fused)
+    if fused:
+        with pltpu.force_tpu_interpret_mode():
+            out = gmm(x, w)
+            got = jax.value_and_grad(loss(gmm), argnums=(0, 1))(x, w)
+    else:
+        out = gmm(x, w)
+        got = jax.value_and_grad(loss(gmm), argnums=(0, 1))(x, w)
+    assert not np.asarray(out[64:]).any()
     want = jax.value_and_grad(loss(einsum), argnums=(0, 1))(x, w)
     for g, e in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, e, rtol=1e-4, atol=1e-4)
